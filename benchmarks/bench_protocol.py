@@ -35,6 +35,7 @@ from common import emit, time_fn
 
 from repro.core import protocol, sigmoid_poly
 from repro.kernels import ops as kernel_ops
+from repro.launch.mesh import auto_mesh
 
 # (K, T, r, c) sweeps at N=8; threshold (2r+1)(K+T-1)+1 must stay <= 8.
 DEFAULT_SETTINGS = [
@@ -74,7 +75,7 @@ def bench_setting(K: int, T: int, r: int, c: int, m: int, d: int,
                                   backend=backend)
         fn = round_fn(cfg)
         if backend == "shard":
-            with mesh:
+            with jax.set_mesh(mesh):
                 us = time_fn(fn, key, w)
         else:
             us = time_fn(fn, key, w)
@@ -127,7 +128,7 @@ def main(argv=None) -> int:
             args.m = 256
         if args.d == DEFAULT_D:
             args.d = 64
-    mesh = jax.make_mesh((N_WORKERS,), ("workers",))
+    mesh = auto_mesh((N_WORKERS,), ("workers",))
     settings = [bench_setting(K, T, r, c, args.m, args.d, mesh)
                 for (K, T, r, c) in settings_sweep]
     report = {

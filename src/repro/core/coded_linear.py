@@ -111,8 +111,7 @@ def coded_head_apply_sharded(cfg: CodedLinearConfig, mesh, axis: str,
         res = worker_matmul(cfg, h_q, ws[0])[None]          # (1, m, v/K)
         return jax.lax.all_gather(res, axis, axis=0, tiled=True)  # (N, m, v/K)
 
-    from repro.parallel import compat
-    results = compat.shard_map(body, mesh, (Pspec(axis),), Pspec(),
-                               check=True)(w_shares)
+    results = jax.shard_map(body, mesh=mesh, in_specs=(Pspec(axis),),
+                            out_specs=Pspec())(w_shares)
     picked = jnp.take(results, jnp.asarray(surv[: cfg.threshold]), axis=0)
     return decode_output(cfg, picked, surv[: cfg.threshold])

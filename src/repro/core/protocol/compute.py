@@ -7,9 +7,11 @@ W̃ (d, c, r) -> result (d, c); the dominant X̃ read is amortized across heads.
 
 Backend matrix (DESIGN.md §4):
   * "vmap"     — all N workers simulated on one device (tests/benchmarks).
-  * "shard"    — shard_map over a mesh axis: one coded share per device,
-                 zero collectives in the worker step (the paper's key
-                 property), one all_gather for "send results to master".
+  * "shard"    — shard_map over the active mesh's worker axis: each of
+                 the D devices evaluates its block of N/D coded shares
+                 (D must divide N) with zero collectives in the worker step
+                 (the paper's key property), then one all_gather plays
+                 "send results to master".
   * use_kernel — routes the per-worker computation through the fused Pallas
                  kernel (kernels/coded_grad.py) on EITHER backend.
 """
@@ -55,20 +57,30 @@ def all_worker_results(cfg: CPMLConfig, cbar: jax.Array, x_shares: jax.Array,
     if cfg.backend == "vmap":
         return jax.vmap(f)(x_shares, w_shares)
     elif cfg.backend == "shard":
-        from repro.parallel import compat
-        mesh = compat.ambient_mesh()  # inside with-mesh / set_mesh context
+        from jax.sharding import PartitionSpec as Pspec
         axis = cfg.mesh_axis
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty or axis not in mesh.axis_names:
+            raise ValueError(
+                f"backend='shard' needs an active mesh with a {axis!r} axis: "
+                f"run under `with jax.set_mesh(mesh):`")
+        n_dev = mesh.shape[axis]
+        if cfg.N % n_dev:
+            raise ValueError(
+                f"backend='shard': {n_dev} devices on mesh axis {axis!r} do "
+                f"not divide N={cfg.N} workers; each device evaluates an "
+                f"equal block of N/D shares")
 
         def shard_body(xs, ws):
-            res = f(xs[0], ws[0])[None]
+            # this device's block of N/D workers, with no collective
+            res = jax.vmap(f)(xs, ws)
             # "send result back to the master": one collective, results
             # replicated so the (replicated) decode can run everywhere.
             return jax.lax.all_gather(res, axis, axis=0, tiled=True)
 
-        from jax.sharding import PartitionSpec as Pspec
-        # check=False: the all_gather makes the output replicated, but the
-        # static replication check cannot infer that.
-        return compat.shard_map(shard_body, mesh,
-                                (Pspec(axis), Pspec(axis)),
-                                Pspec())(x_shares, w_shares)
+        # check_vma=False: the all_gather makes the output replicated, but
+        # the static replication check cannot infer that.
+        return jax.shard_map(shard_body, in_specs=(Pspec(axis), Pspec(axis)),
+                             out_specs=Pspec(), check_vma=False
+                             )(x_shares, w_shares)
     raise ValueError(cfg.backend)

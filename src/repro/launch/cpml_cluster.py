@@ -717,6 +717,9 @@ def main(argv: list[str] | None = None) -> int:
     rc = _validate(args)
     if rc is not None:
         return rc
+    from repro.launch import device
+    print(device.device_line())
+    device.enable_compile_cache()
 
     if args.protocol == "mpc":
         return _run_mpc(args)

@@ -111,8 +111,12 @@ def main(argv: list[str] | None = None) -> int:
     from repro.cluster.latency import SleepyStragglerLatency
     from repro.cluster.serve import (
         PredictionServer, ServeConfig, open_loop_queries)
+    from repro.launch import device
     from repro.launch.cpml_cluster import (
         _json_finite, _recorder_for, local_socket_cluster)
+
+    print(device.device_line())
+    device.enable_compile_cache()
 
     cfg = ServeConfig(N=args.workers, K=args.parallel, T=args.privacy,
                       max_batch=args.max_batch, max_wait_s=args.max_wait,

@@ -5,15 +5,15 @@
 Builds a synthetic classification task, runs the scan-jitted coded engine
 (multi-class one-vs-all + optional mini-batch SGD + optional straggler
 schedule), and reports accuracy against the cleartext quantized baseline.
-``--backend shard`` forces an N-device host mesh (one coded share per
-device, the paper's deployment shape); ``--kernel`` routes the worker step
-through the fused Pallas kernel.
+``--backend shard`` spreads the N workers over every device JAX sees, a
+block of N/D coded shares per device (the paper's deployment shape at
+D = N); ``--kernel`` routes the worker step through the fused Pallas
+kernel.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 
@@ -49,16 +49,17 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
-    if args.backend == "shard" and "XLA_FLAGS" not in os.environ:
-        # one device per worker BEFORE jax initializes
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.workers}")
 
     import jax
     import numpy as np
 
     from repro.core import field, protocol
     from repro.data import synthetic
+    from repro.launch import device
+    from repro.launch.mesh import auto_mesh
+
+    print(device.device_line())
+    device.enable_compile_cache()
 
     cfg = protocol.CPMLConfig(
         N=args.workers, K=args.parallel, T=args.privacy, r=args.degree,
@@ -92,10 +93,8 @@ def main(argv: list[str] | None = None) -> int:
 
     t0 = time.time()
     if args.backend == "shard":
-        assert jax.device_count() >= cfg.N, (
-            f"shard backend wants {cfg.N} devices, have {jax.device_count()}")
-        mesh = jax.make_mesh((cfg.N,), (cfg.mesh_axis,))
-        with mesh:
+        mesh = auto_mesh((jax.device_count(),), (cfg.mesh_axis,))
+        with jax.set_mesh(mesh):
             w, hist = run()
     else:
         w, hist = run()

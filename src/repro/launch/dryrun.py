@@ -189,8 +189,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     if save_hlo:
         import gzip
         with gzip.open(save_hlo, "wt") as f:

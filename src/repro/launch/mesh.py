@@ -13,20 +13,18 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across versions: axis_types only exists on newer jax
-    (jax <= 0.4.x meshes are implicitly Auto on every axis)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+def auto_mesh(shape, axes, devices=None):
+    """jax.make_mesh with every axis Auto (jax.make_mesh defaults to
+    Explicit): shardings are propagated by the compiler, and shard_map /
+    NamedSharding / with_sharding_constraint state them where it matters."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1):
@@ -34,7 +32,7 @@ def make_local_mesh(model: int = 1):
     n = len(jax.devices())
     model = min(model, n)
     data = n // model
-    return compat_make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def mesh_chips(mesh) -> int:

@@ -30,7 +30,7 @@ from repro.runtime.resilience import ResilientLoop
 
 def build_sharded_state(cfg, rc, ocfg, mesh, key):
     pspecs = M.param_specs(cfg, mesh, rc.seq_parallel)
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         params = jax.jit(
             lambda k: M.init_params(cfg, k),
             out_shardings=jax.tree.map(lambda s: NamedSharding(mesh, s),

@@ -204,9 +204,8 @@ def context_parallel_attention(mesh, q: jax.Array, k: jax.Array,
             kv_block=kv_block, softcap=softcap, compute_dtype=compute_dtype,
             row_offset=off)
 
-    from repro.parallel import compat
-    return compat.shard_map(body, mesh, (spec, spec, spec), spec,
-                            check=True)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

@@ -105,8 +105,9 @@ def test_checkpoint_async(tmp_path):
 def test_elastic_restore_with_sharding(tmp_path):
     """Restore places leaves with provided shardings (1-device 'mesh')."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("data",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1,), ("data",))
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
     mgr = CheckpointManager(str(tmp_path), async_write=False)
     mgr.save(2, {"params": {"w": jnp.ones((4, 4))}})
     sh = {"params": {"w": NamedSharding(mesh, P("data", None))}}

@@ -68,9 +68,10 @@ def test_paper_accuracy_reproduction():
     assert abs(acc_coded - float(acc_ref)) < 0.03
 
 
-def test_cpml_train_driver(tmp_path):
+def test_cpml_train_driver(tmp_path, capsys):
     """The coded-workload CLI end to end: multi-class + mini-batch + a
-    straggler every round, json metrics out."""
+    straggler every round, json metrics out.  Its first line names the
+    device it ran on."""
     from repro.launch import cpml_train
     out = tmp_path / "cpml.json"
     rc = cpml_train.main(["--classes", "3", "--m", "300", "--d", "24",
@@ -78,6 +79,8 @@ def test_cpml_train_driver(tmp_path):
                           "--batch-rows", "32", "--drop-workers", "1",
                           "--json-out", str(out)])
     assert rc == 0
+    first = capsys.readouterr().out.splitlines()[0]
+    assert first.startswith("device: platform=cpu kind="), first
     import json
     rep = json.loads(out.read_text())
     assert rep["config"]["c"] == 3 and len(rep["history"]) == 2
@@ -93,15 +96,16 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import protocol
 from repro.data import synthetic
+from repro.launch.mesh import auto_mesh
 
 x, y = synthetic.mnist_like(jax.random.PRNGKey(42), m=400, d=30)
-mesh = jax.make_mesh((8,), ("workers",))
+mesh = auto_mesh((8,), ("workers",))
 cfgv = protocol.CPMLConfig(N=8, K=2, T=1, r=1, backend="vmap")
 sv = protocol.setup(cfgv, jax.random.PRNGKey(0), x, y)
 wv = protocol.step(cfgv, jax.random.PRNGKey(1), sv, 0.5).w
 cfgs = protocol.CPMLConfig(N=8, K=2, T=1, r=1, backend="shard")
 ss = protocol.setup(cfgs, jax.random.PRNGKey(0), x, y)
-with mesh:
+with jax.set_mesh(mesh):
     ws = protocol.step(cfgs, jax.random.PRNGKey(1), ss, 0.5).w
 assert np.allclose(np.asarray(wv), np.asarray(ws), atol=1e-6), \
     float(jnp.abs(wv - ws).max())
@@ -112,7 +116,7 @@ for kern in (False, True):
                                use_kernel=kern)
     xm, ym = synthetic.multiclass_mnist_like(jax.random.PRNGKey(2), m=240,
                                              d=24, c=3)
-    with mesh:
+    with jax.set_mesh(mesh):
         w1, _ = protocol.train(cfgk, jax.random.PRNGKey(5), xm, ym, iters=10)
         w2, _ = protocol.train_reference(cfgk, jax.random.PRNGKey(5), xm, ym,
                                          iters=10)
@@ -123,6 +127,40 @@ print("SHARD_OK")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "SHARD_OK" in out.stdout, out.stdout + out.stderr
+
+
+def test_shard_backend_blocks_of_shares():
+    """N=8 workers on 4 devices: each device evaluates a block of 2 shares,
+    and training is bit-identical to vmap.  A device count that does not
+    divide N is refused with a clear error."""
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.core import protocol
+from repro.data import synthetic
+from repro.launch.mesh import auto_mesh
+
+x, y = synthetic.mnist_like(jax.random.PRNGKey(3), m=240, d=24)
+cfgv = protocol.CPMLConfig(N=8, K=2, T=1, r=1, backend="vmap")
+wv, _ = protocol.train(cfgv, jax.random.PRNGKey(5), x, y, iters=4)
+cfgs = protocol.CPMLConfig(N=8, K=2, T=1, r=1, backend="shard")
+with jax.set_mesh(auto_mesh((4,), ("workers",))):
+    ws, _ = protocol.train(cfgs, jax.random.PRNGKey(5), x, y, iters=4)
+assert (np.asarray(wv) == np.asarray(ws)).all()
+with jax.set_mesh(auto_mesh((3,), ("workers",), devices=jax.devices()[:3])):
+    try:
+        protocol.train(cfgs, jax.random.PRNGKey(5), x, y, iters=1)
+    except ValueError as e:
+        assert "do not divide N=8" in str(e), e
+    else:
+        raise AssertionError("3 devices for N=8 was not refused")
+print("BLOCKS_OK")
+"""
+    env = dict(os.environ, PYTHONPATH=SRC)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "BLOCKS_OK" in out.stdout, out.stdout + out.stderr
 
 
 @pytest.mark.slow
