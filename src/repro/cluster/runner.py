@@ -79,6 +79,7 @@ from repro.core.protocol.config import CPMLConfig
 # decode with a tracked error budget).  Both expose the same hook factories.
 ENGINES = {"exact": engine, "alcc": alcc_engine}
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import phase
 from repro.runtime.resilience import HeartbeatMonitor, ResilientLoop
 
 
@@ -777,23 +778,22 @@ class ClusterRunner:
         emitted while it is open (so they nest), and a starved round leaves
         an instant marker + counter bump before the error propagates to the
         resilient loop."""
-        rspan = self.obs.begin("round", round=t, replayed=replayed)
-        try:
-            trace = self._step_round_inner(t, iters, replayed)
-            self._observe_round(t, trace, self.records[t])
-            return trace
-        except ClusterDecodeError:
-            self.obs.instant("starved", round=t)
-            self._m_starved.inc()
-            raise
-        finally:
-            self.obs.end(rspan)
+        with phase("round", self.obs, round=t, replayed=replayed):
+            try:
+                trace = self._step_round_inner(t, iters, replayed)
+                self._observe_round(t, trace, self.records[t])
+                return trace
+            except ClusterDecodeError:
+                self.obs.instant("starved", round=t)
+                self._m_starved.inc()
+                raise
 
     def _step_round_inner(self, t: int, iters: int, replayed: bool = False
                           ) -> RoundTrace:
         cfg = self.cfg
-        view = self._membership_fence(t)
-        workers = self.dispatch_set(view)
+        with phase("fence"):
+            view = self._membership_fence(t)
+            workers = self.dispatch_set(view)
         if len(workers) < cfg.threshold:
             raise ClusterDecodeError(
                 f"round {t}: only {len(workers)} dispatchable workers < "
@@ -810,7 +810,9 @@ class ClusterRunner:
             ctx.epoch = view.epoch
             self.obs.instant("prefetch_epoch_invalidated", round=t,
                              epoch=view.epoch)
-        key_t = None if ctx is not None else self.eng.round_key(self.kloop, t)
+        with phase("round_key"):
+            key_t = (None if ctx is not None
+                     else self.eng.round_key(self.kloop, t))
         # the subset the streaming decode would fold against this round
         # (ctx.plan when prefetched — possibly one round staler — else the
         # last observed order); used for the decoder plan in distributed
@@ -915,7 +917,8 @@ class ClusterRunner:
             # count (the ill-conditioned fallback reads ALL responders)
             _, order, _ = self.eng.survivor_round_info(cfg, trace.responders)
         else:
-            dmat, order = engine.survivor_round(cfg, trace.responders)
+            with phase("decode_matrix"):
+                dmat, order = engine.survivor_round(cfg, trace.responders)
         if self.distributed:
             if decoder is not None:
                 # the shares are already folded (or retained) — finish is
@@ -936,16 +939,20 @@ class ClusterRunner:
                 self.w2 = self._update(self.w2, jnp.asarray(fastest),
                                        jnp.asarray(dmat, jnp.int32), bidx)
             self.w2.block_until_ready()   # honest decode_s measurement
-        elif ctx is not None:
-            self.w2 = self._round_split(ctx.kq, ctx.mask_shares, self.w2,
-                                        jnp.asarray(dmat, jnp.int32),
-                                        jnp.asarray(order, jnp.int32), bidx)
-        elif alcc:
-            self.w2 = self._round(key_t, self.w2, order, bidx)
         else:
-            self.w2 = self._round(key_t, self.w2,
-                                  jnp.asarray(dmat, jnp.int32),
-                                  jnp.asarray(order, jnp.int32), bidx)
+            with phase("round_program"):
+                if ctx is not None:
+                    self.w2 = self._round_split(
+                        ctx.kq, ctx.mask_shares, self.w2,
+                        jnp.asarray(dmat, jnp.int32),
+                        jnp.asarray(order, jnp.int32), bidx)
+                elif alcc:
+                    self.w2 = self._round(key_t, self.w2, order, bidx)
+                else:
+                    self.w2 = self._round(key_t, self.w2,
+                                          jnp.asarray(dmat, jnp.int32),
+                                          jnp.asarray(order, jnp.int32),
+                                          bidx)
         decode_wall_s = _time.perf_counter() - dec_t0
         if self.distributed:
             # real transport: the scheduler cannot see master-side encode/

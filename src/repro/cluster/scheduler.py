@@ -59,7 +59,7 @@ from repro.cluster.messages import (
     worker_endpoint,
 )
 from repro.cluster.transport import InProcessTransport, Transport
-from repro.obs.trace import NULL_RECORDER
+from repro.obs.trace import NULL_RECORDER, phase
 
 
 class ClusterDecodeError(RuntimeError):
@@ -449,13 +449,13 @@ class EventScheduler:
         wire0 = (self.transport.wire_totals()
                  if hasattr(self.transport, "wire_totals") else None)
         t0 = self.time.now()
-        with self.obs.span("dispatch", round=round, workers=len(workers)):
+        with phase("dispatch", self.obs, round=round, workers=len(workers)):
             sampled = self._send_round(round, workers, t0, payloads)
 
         dispatched = {int(w) for w in workers}
         deadline = t0 + timeout_s
         worker_traces: dict[int, Any] = {}
-        with self.obs.span("collect", round=round):
+        with phase("collect", self.obs, round=round):
             arrivals, latencies, responders, round_payloads = self._collect(
                 round, threshold, dispatched, monitor, deadline,
                 collect_all=collect_all, result_type=WorkerResult,

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core import field, quantize
 from repro.core.protocol.config import CPMLConfig
+from repro.obs.trace import phase
 
 
 def make_decode_matrix(cfg: CPMLConfig, survivors: np.ndarray) -> jax.Array:
@@ -40,9 +41,12 @@ def _cached_decode_matrix(scheme, survivors: tuple[int, ...]) -> np.ndarray:
     """Host Lagrange-coefficient solve, cached per (scheme, pattern).
 
     Training loops reuse a handful of survivor patterns across thousands of
-    rounds; the O(R^2 K) host solve runs once per pattern.
+    rounds; the O(R^2 K) host solve runs once per pattern.  Its phase is
+    opened only on a cache miss, so the count of ``cpml.decode_solve``
+    annotations is the count of misses.
     """
-    return scheme.decode_matrix(np.asarray(survivors))
+    with phase("decode_solve"):
+        return scheme.decode_matrix(np.asarray(survivors))
 
 
 def decode_parts(cfg: CPMLConfig, results: jax.Array,

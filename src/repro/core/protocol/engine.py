@@ -30,7 +30,18 @@ import numpy as np
 from repro.core import quantize, sigmoid_poly
 from repro.core.protocol import compute, decode, encode
 from repro.core.protocol.config import CPMLConfig
+from repro.obs.trace import phase
 
+# device scopes of one round (jax.named_scope): they name the ops of the
+# weight encode, the worker polynomial and the decode + gradient step in
+# the compiled program's op_name metadata, shared by _round and the scan
+SCOPE_ENCODE = "cpml_encode_weights"
+SCOPE_WORKER = "cpml_worker"
+SCOPE_DECODE = "cpml_decode"
+# The scopes live only in op metadata, which JAX's persistent compile cache
+# leaves out of its key by default: an executable cached by a build of the
+# same program without them comes back with stale op_names in its profile.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 # ---------------------------------------------------------------------------
 # State + setup
@@ -68,7 +79,8 @@ def setup(cfg: CPMLConfig, key: jax.Array, x: jax.Array, y: jax.Array,
     """
     kx, _ = jax.random.split(key)
     encoder = dataset_encoder or encode.encode_dataset
-    x_shares, ctx = encoder(cfg, kx, x)
+    with phase("setup.encode_dataset"):
+        x_shares, ctx = encoder(cfg, kx, x)
     xq_real = quantize.dequantize(ctx["xq"], cfg.lx, cfg.p)
     m_padded = ctx["m_padded"]
     mk = m_padded // cfg.K
@@ -137,9 +149,10 @@ def _round_update(cfg: CPMLConfig, w2: jax.Array, fastest: jax.Array,
     Both paths flow through THIS function, so where the worker compute ran
     cannot change what the update computes.
     """
-    xg = decode.decode_gradient(cfg, fastest, dmat)                # (d, c)
-    return _gradient_step(cfg, w2, xg, xq_parts, y_parts, xty_full,
-                          batch_idx, eta, m_int)
+    with jax.named_scope(SCOPE_DECODE):
+        xg = decode.decode_gradient(cfg, fastest, dmat)            # (d, c)
+        return _gradient_step(cfg, w2, xg, xq_parts, y_parts, xty_full,
+                              batch_idx, eta, m_int)
 
 
 def _update_from_parts(cfg: CPMLConfig, w2: jax.Array, parts: jax.Array,
@@ -170,7 +183,8 @@ def _round_body(cfg: CPMLConfig, w_shares: jax.Array, w2: jax.Array,
     xb = (x_shares if batch_idx is None
           else jnp.take(x_shares, batch_idx, axis=1))    # (N, b, d): the
     # coded sub-batch is the SAME row subset of every share / part.
-    results = compute.all_worker_results(cfg, cbar, xb, w_shares)  # (N, d, c)
+    with jax.named_scope(SCOPE_WORKER):
+        results = compute.all_worker_results(cfg, cbar, xb, w_shares)
     fastest = jnp.take(results, order, axis=0)                     # (R, d, c)
     return _round_update(cfg, w2, fastest, xq_parts, y_parts, xty_full,
                          dmat, batch_idx, eta, m_int)
@@ -183,7 +197,8 @@ def _round(cfg: CPMLConfig, key: jax.Array, w2: jax.Array,
            ) -> jax.Array:
     """w2 (d, c) -> updated (d, c).  One full encode->compute->decode round
     with the N workers enacted on-device (vmap/shard, DESIGN.md §4)."""
-    w_shares = encode.encode_weights(cfg, key, w2)       # (N, d, c, r)
+    with jax.named_scope(SCOPE_ENCODE):
+        w_shares = encode.encode_weights(cfg, key, w2)   # (N, d, c, r)
     return _round_body(cfg, w_shares, w2, x_shares, xq_parts, y_parts,
                        xty_full, dmat, order, batch_idx, eta, m_int)
 
@@ -199,7 +214,8 @@ def _round_split(cfg: CPMLConfig, kq: jax.Array, mask_shares: jax.Array,
     the pipeline prefetcher while the PREVIOUS round was in flight.  The
     encode split is exact, so this is bit-identical to _round on the same
     round key (tests/test_pipeline.py)."""
-    w_shares = encode.encode_weights_finish(cfg, kq, mask_shares, w2)
+    with jax.named_scope(SCOPE_ENCODE):
+        w_shares = encode.encode_weights_finish(cfg, kq, mask_shares, w2)
     return _round_body(cfg, w_shares, w2, x_shares, xq_parts, y_parts,
                        xty_full, dmat, order, batch_idx, eta, m_int)
 
@@ -473,24 +489,27 @@ def train(cfg: CPMLConfig, key: jax.Array, x: jax.Array, y: jax.Array,
           survivor_fn: Callable[[int], np.ndarray] | None = None,
           eval_every: int = 0) -> tuple[jax.Array, list[dict[str, float]]]:
     """Full Algorithm 1 as ONE jitted scan.  Returns (w, history)."""
-    ksetup, kloop = jax.random.split(key)
-    state = setup(cfg, ksetup, x, y)
-    if eta is None:
-        eta = lipschitz_eta(state.xq_real)
-    sched = make_schedule(cfg, kloop, iters, state.mk, survivor_fn)
-    w2, metrics = _train_scan(
-        cfg, int(eval_every), _w_internal(cfg, state.w), state.x_shares,
-        state.xq_parts, state.y_parts, _w_internal(cfg, state.xty), sched.keys,
-        sched.decode_mats, sched.orders, sched.batch_idx,
-        *_scale_args(cfg, eta, state),
-        state.xq_real[: state.m], state.y[: state.m])
-    history: list[dict[str, float]] = []
-    if eval_every:
-        losses, accs = metrics
-        for t in range(eval_every - 1, iters, eval_every):
-            history.append({"iter": t + 1, "loss": float(losses[t]),
-                            "acc": float(accs[t])})
-    return _w_public(cfg, w2), history
+    with phase("train"):
+        ksetup, kloop = jax.random.split(key)
+        state = setup(cfg, ksetup, x, y)
+        if eta is None:
+            with phase("setup.step_size"):
+                eta = lipschitz_eta(state.xq_real)
+        with phase("setup.schedule"):
+            sched = make_schedule(cfg, kloop, iters, state.mk, survivor_fn)
+        w2, metrics = _train_scan(
+            cfg, int(eval_every), _w_internal(cfg, state.w), state.x_shares,
+            state.xq_parts, state.y_parts, _w_internal(cfg, state.xty),
+            sched.keys, sched.decode_mats, sched.orders, sched.batch_idx,
+            *_scale_args(cfg, eta, state),
+            state.xq_real[: state.m], state.y[: state.m])
+        history: list[dict[str, float]] = []
+        if eval_every:
+            losses, accs = metrics
+            for t in range(eval_every - 1, iters, eval_every):
+                history.append({"iter": t + 1, "loss": float(losses[t]),
+                                "acc": float(accs[t])})
+        return _w_public(cfg, w2), history
 
 
 def train_reference(cfg: CPMLConfig, key: jax.Array, x: jax.Array,

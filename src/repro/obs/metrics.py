@@ -10,8 +10,7 @@ out of the span trace, not the metrics.  Two export formats:
   * ``snapshot()`` — a plain JSON-able dict (bench reports, tests).
 
 Updating a metric is a couple of dict/float operations; the registry is
-always on (like the wire byte counters it aggregates) and its cost rides
-under the same bench_cluster.py overhead gate as the recorder.
+always on (like the wire byte counters it aggregates).
 """
 from __future__ import annotations
 

@@ -18,8 +18,17 @@ only WITHIN a process and are never compared across clock domains.
 
 ``NullRecorder`` is the off-by-default path: every method is a constant
 no-op (shared singleton context manager, no allocation, no clock read), so
-instrumented code costs nothing when tracing is off — the overhead gate in
-benchmarks/bench_cluster.py holds the recorder to that claim.
+instrumented code costs next to nothing when tracing is off.
+benchmarks/bench_cluster.py checks that a live recorder never advances the
+simulated clock and leaves the weights bit-identical; what tracing costs in
+wall time is measured on the chip (PERF.md).
+
+``phase`` is the one span API for program code whose time is measured on
+the chip: it always enters a ``jax.profiler.TraceAnnotation`` named
+``cpml.<name>`` (the profiler's host clock, the one the device planes of
+the same trace use; with no profiler session active it costs a few hundred
+ns), and also the recorder's span, so a live ``Recorder`` still gets it.
+The annotation lives in ``phase``, never in ``NullRecorder``.
 """
 from __future__ import annotations
 
@@ -28,6 +37,8 @@ import math
 import threading
 import time as _time
 from typing import Any, Callable
+
+from jax.profiler import TraceAnnotation
 
 MASTER_PROCESS = "master"
 MASTER_TRACK = "master"
@@ -264,6 +275,33 @@ class NullRecorder:
 
 
 NULL_RECORDER = NullRecorder()
+
+PHASE_PREFIX = "cpml."
+
+
+class _PhaseScope:
+    __slots__ = ("_annotation", "_scope")
+
+    def __init__(self, annotation: TraceAnnotation, scope):
+        self._annotation, self._scope = annotation, scope
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return self._scope.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        try:
+            self._scope.__exit__(*exc)
+        finally:
+            self._annotation.__exit__(*exc)
+
+
+def phase(name: str, recorder=NULL_RECORDER, **args) -> _PhaseScope:
+    """Context manager for one phase of the program: a profiler annotation
+    ``cpml.<name>`` and ``recorder.span(name, **args)`` inside it.  The
+    recorder's args never reach the annotation, whose name stays fixed."""
+    return _PhaseScope(TraceAnnotation(PHASE_PREFIX + name),
+                       recorder.span(name, **args))
 
 
 def structure(rec, process: str = MASTER_PROCESS
