@@ -74,21 +74,68 @@ class CodingScheme:
         return field.host_vandermonde_inv(pts, self.p)
 
 
+def _limb_weights(U: np.ndarray, p: int) -> np.ndarray:
+    """Host constant of ``combine``: (nl, N, nl·rows) 8-bit limbs.
+
+    Entry [j, n, i·rows + k] is limb j of 2^{8i}·U[k, n] mod p.  Against
+    the data's limb i in row block i, this folds each data limb's weight
+    2^{8i} into U on the host, so the device recombines only the nl
+    weights 2^{8j} of U's own limbs.
+    """
+    nl = field.n_limbs(p)
+    U = np.asarray(U, np.int64) % p
+    V = np.concatenate([U * pow(2, field.LIMB_BITS * i, p) % p
+                        for i in range(nl)])                 # (nl·rows, N)
+    L = np.stack([(V >> (field.LIMB_BITS * j)) & field.LIMB_MASK
+                  for j in range(nl)])                       # (nl, nl·rows, N)
+    return L.transpose(0, 2, 1)
+
+
+def combine(U: np.ndarray, flat: jax.Array, p: int) -> jax.Array:
+    """(Uᵀ @ flat) mod p for a host-constant (rows, N) U: (N, E) int32.
+
+    The encode's contraction is only rows = K+T (or K, or T) deep, so it
+    is not a general ``field.matmul`` (DESIGN.md §3, "The encode's narrow
+    contraction").  ``flat`` (rows, E) is split into its nl 8-bit limbs;
+    for each limb j of ``_limb_weights(U)`` one bf16 dot contracts all nl
+    data limbs and all rows at once.  Each product is < 2^16 and each of
+    the nl accumulators sums nl·rows of them, so while
+    nl·rows·255² < min(p, 2^24) the f32 sums are exact and already below
+    p, and no remainder is taken.  Horner over the accumulators in steps
+    of 2^8 recombines them: 8·(nl-1) modular doublings an element.  A
+    deeper contraction takes ``field.matmul``.
+    """
+    rows, N = U.shape
+    nl = field.n_limbs(p)
+    if nl * rows * field.LIMB_MASK ** 2 >= min(p, 1 << 24):
+        return field.matmul(jnp.asarray(np.asarray(U).T, jnp.int32), flat, p)
+    L = jnp.asarray(_limb_weights(U, p), jnp.bfloat16)       # (nl, N, nl·rows)
+    L = L.reshape(nl, N, nl, rows)
+    shifts = jnp.arange(nl, dtype=jnp.int32)[:, None, None] * field.LIMB_BITS
+    X = ((flat[None] >> shifts) & field.LIMB_MASK).astype(jnp.bfloat16)
+
+    def acc(j):
+        return jnp.einsum("nik,ike->ne", L[j], X,
+                          preferred_element_type=jnp.float32).astype(jnp.int32)
+    out = acc(nl - 1)
+    for j in range(nl - 2, -1, -1):
+        out = field.addmod(field.double_mod(out, field.LIMB_BITS, p),
+                           acc(j), p)
+    return out
+
+
 def encode(scheme: CodingScheme, x_parts: jax.Array, masks: jax.Array,
            p: int | None = None) -> jax.Array:
     """Encode stacked parts+masks into N shares (Eq. 12).
 
     x_parts: (K, *part_shape) int32 field elements.
     masks:   (T, *part_shape) uniform field elements (the Z_i / V_i).
-    Returns shares: (N, *part_shape).
+    Returns shares: (N, *part_shape), each element the (K+T)-term
+    combination ``combine`` computes, exact mod p.
     """
     p = p or scheme.p
     stacked = jnp.concatenate([x_parts, masks], axis=0) if scheme.T else x_parts
-    part_shape = stacked.shape[1:]
-    flat = stacked.reshape(scheme.K + scheme.T, -1)
-    U = jnp.asarray(scheme.encode_matrix, jnp.int32)  # (K+T, N)
-    shares = field.matmul(U.T, flat, p)               # (N, prod(part_shape))
-    return shares.reshape(scheme.N, *part_shape)
+    return _encode_rows(scheme, stacked, slice(0, scheme.K + scheme.T), p)
 
 
 def _encode_rows(scheme: CodingScheme, stacked: jax.Array, rows: slice,
@@ -96,8 +143,7 @@ def _encode_rows(scheme: CodingScheme, stacked: jax.Array, rows: slice,
     """Shares contributed by a contiguous row-slice of the encode matrix U."""
     part_shape = stacked.shape[1:]
     flat = stacked.reshape(stacked.shape[0], -1)
-    U = jnp.asarray(scheme.encode_matrix[rows], jnp.int32)   # (nrows, N)
-    shares = field.matmul(U.T, flat, p)                      # (N, prod(shape))
+    shares = combine(scheme.encode_matrix[rows], flat, p)  # (N, prod(shape))
     return shares.reshape(scheme.N, *part_shape)
 
 
@@ -106,8 +152,8 @@ def encode_data(scheme: CodingScheme, x_parts: jax.Array,
     """The data-row contribution U[:K]ᵀ X̄ of a split encode.
 
     ``addmod(encode_data(parts), encode_masks(masks)) == encode(parts,
-    masks)`` bit-for-bit: field.matmul/addmod are exact mod p, so splitting
-    the (K+T)-row matmul into its K-row and T-row halves changes nothing.
+    masks)`` bit-for-bit: combine/addmod are exact mod p, so splitting the
+    (K+T)-row combination into its K-row and T-row halves changes nothing.
     This is the W-DEPENDENT half of a round's weight encode — the only part
     that must wait for the previous round's decoded weights.
     """

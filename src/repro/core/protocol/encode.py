@@ -8,6 +8,7 @@ elementwise/linearly, so the c heads ride through a single encode.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -25,17 +26,35 @@ def pad_rows(x: jax.Array, K: int) -> jax.Array:
     return x
 
 
+# device scope of the dataset encode (jax.named_scope), beside the round's
+# scopes in engine: it names every op of the compiled encode in op_name
+SCOPE_ENCODE_DATASET = "cpml_encode_dataset"
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _encode_dataset(cfg: CPMLConfig, key: jax.Array, x: jax.Array
+                    ) -> tuple[jax.Array, jax.Array]:
+    with jax.named_scope(SCOPE_ENCODE_DATASET):
+        xq = quantize.quantize_data(x, cfg.lx, cfg.p)      # (m, d) field
+        xq = pad_rows(xq, cfg.K)
+        mk = xq.shape[0] // cfg.K
+        # no optimization_barrier on the parts: on a v5e one changed the
+        # masks' contribution to the shares (DESIGN.md §3)
+        parts = xq.reshape(cfg.K, mk, xq.shape[-1])
+        masks = lagrange.draw_masks(key, cfg.T, parts.shape[1:], cfg.p)
+        return lagrange.encode(cfg.scheme, parts, masks, cfg.p), xq
+
+
 def encode_dataset(cfg: CPMLConfig, key: jax.Array, x: jax.Array
                    ) -> tuple[jax.Array, dict[str, Any]]:
-    """Returns shares (N, m/K, d) + master-side cleartext context."""
-    xq = quantize.quantize_data(x, cfg.lx, cfg.p)          # (m, d) field
-    xq = pad_rows(xq, cfg.K)
-    mk = xq.shape[0] // cfg.K
-    parts = xq.reshape(cfg.K, mk, xq.shape[-1])
-    masks = lagrange.draw_masks(key, cfg.T, parts.shape[1:], cfg.p)
-    shares = lagrange.encode(cfg.scheme, parts, masks, cfg.p)
-    ctx = {"xq": xq, "m_padded": xq.shape[0]}
-    return shares, ctx
+    """Returns shares (N, m/K, d) + master-side cleartext context.
+
+    Quantize, pad, split, mask draw and encode run as ONE compiled program
+    (``cfg`` static), traced once per (cfg, shapes): each call still draws
+    its own masks from ``key`` and encodes its own ``x``.
+    """
+    shares, xq = _encode_dataset(cfg, key, x)
+    return shares, {"xq": xq, "m_padded": xq.shape[0]}
 
 
 def encode_weights(cfg: CPMLConfig, key: jax.Array, w: jax.Array) -> jax.Array:

@@ -38,6 +38,8 @@ from repro.obs.trace import phase
 SCOPE_ENCODE = "cpml_encode_weights"
 SCOPE_WORKER = "cpml_worker"
 SCOPE_DECODE = "cpml_decode"
+# and the scope of each job's compiled dataset encode (engine.setup)
+SCOPE_ENCODE_DATASET = encode.SCOPE_ENCODE_DATASET
 # The scopes live only in op metadata, which JAX's persistent compile cache
 # leaves out of its key by default: an executable cached by a build of the
 # same program without them comes back with stale op_names in its profile.

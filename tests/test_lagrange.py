@@ -100,3 +100,54 @@ def test_t_collusion_independence(key):
     # uniform on [0,1): mean ~ 0.5, var ~ 1/12
     assert abs(vals.mean() - 0.5) < 0.02
     assert abs(vals.var() - 1 / 12) < 0.005
+
+
+def _oracle_encode(U: np.ndarray, stacked: np.ndarray, p: int) -> np.ndarray:
+    """shares[n, e] = sum_k U[k, n] * stacked[k, e] mod p in python ints."""
+    U = U.astype(object)
+    flat = stacked.reshape(stacked.shape[0], -1).astype(object)
+    return (U.T.dot(flat) % p).astype(np.int64)
+
+
+@pytest.mark.parametrize("p", [field.P, field.P30], ids=["P", "P30"])
+@pytest.mark.parametrize("T", [0, 1, 3])
+@pytest.mark.parametrize("part_shape", [(1,), (3, 5), (10, 13)],
+                         ids=["width1", "odd", "width130"])
+def test_encode_combination_matches_int_oracle(p, T, part_shape):
+    """The (K+T)-term limb combination against python ints, at flat widths
+    1, odd and not a multiple of 128; its split halves add up to it."""
+    s = lagrange.CodingScheme(N=13, K=4, T=T, p=p)
+    rng = np.random.default_rng(T)
+    parts = rng.integers(0, p, (4, *part_shape))
+    parts[0] = p - 1                                   # every limb at 255
+    masks = rng.integers(0, p, (T, *part_shape))
+    shares = lagrange.encode(s, jnp.asarray(parts, jnp.int32),
+                             jnp.asarray(masks, jnp.int32), p)
+    stacked = np.concatenate([parts, masks])
+    want = _oracle_encode(s.encode_matrix, stacked, p)
+    assert shares.shape == (13, *part_shape)
+    assert np.array_equal(np.asarray(shares).reshape(13, -1), want)
+    split = field.addmod(
+        lagrange.encode_data(s, jnp.asarray(parts, jnp.int32), p),
+        lagrange.encode_masks(s, jnp.asarray(masks, jnp.int32), p), p)
+    assert np.array_equal(np.asarray(split), np.asarray(shares))
+
+
+@pytest.mark.parametrize("p, rows", [(field.P, 80), (field.P30, 65)],
+                         ids=["P", "P30"])
+def test_combine_deep_contraction_falls_back_exactly(monkeypatch, p, rows):
+    """From nl·rows·255² >= min(p, 2^24) on, the f32 sums could round or
+    pass p, so the combination takes field.matmul; one row less stays on
+    the limb dot.  Both are exact."""
+    calls = []
+    matmul = field.matmul
+    monkeypatch.setattr(field, "matmul",
+                        lambda *a, **k: calls.append(1) or matmul(*a, **k))
+    rng = np.random.default_rng(rows)
+    for r in (rows - 1, rows):
+        U = rng.integers(0, p, (r, 6))
+        U[0] = p - 1
+        flat = np.full((r, 9), p - 1)
+        got = lagrange.combine(U, jnp.asarray(flat, jnp.int32), p)
+        assert np.array_equal(np.asarray(got), _oracle_encode(U, flat, p))
+        assert len(calls) == (r == rows)

@@ -109,6 +109,19 @@ def test_compiled_round_names_its_device_scopes(program):
         assert any(scope in n.split("/") for n in op_names), scope
 
 
+def test_compiled_dataset_encode_names_its_scope():
+    """Every op of the compiled dataset encode lies under its device scope."""
+    from repro.core.protocol import encode
+    cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1)
+    x, _ = synthetic.mnist_like(jax.random.PRNGKey(0), m=64, d=8)
+    text = encode._encode_dataset.lower(
+        cfg, jax.random.PRNGKey(1), x).compile().as_text()
+    ops = [n for n in re.findall(r'op_name="([^"]*)"', text)
+           if n.startswith("jit(")]            # the parameters carry names
+    assert ops
+    assert all(engine.SCOPE_ENCODE_DATASET in n.split("/") for n in ops)
+
+
 def test_a_cached_build_without_the_scopes_does_not_hide_them(tmp_path):
     """JAX's compile cache keys on op metadata here, so an executable
     cached from the same program without the scopes is not reused."""

@@ -126,3 +126,41 @@ def test_extended_prime(dataset):
     w, hist = protocol.train(cfg, jax.random.PRNGKey(7), x, y, iters=8,
                              eval_every=8)
     assert hist[-1]["loss"] < 0.69
+
+
+@pytest.mark.parametrize("p, c, m", [(field.P, 1, 37), (field.P30, 3, 40)],
+                         ids=["P-padded", "P30-c3"])
+def test_compiled_dataset_encode_equals_field_matmul(p, c, m):
+    """The compiled encode_dataset against the general field.matmul over
+    the same quantized parts and the same masks."""
+    from repro.core import lagrange
+    from repro.core.protocol import encode
+    cfg = small_cfg(p=p, c=c, K=3, N=10)
+    x, _ = synthetic.mnist_like(jax.random.PRNGKey(m), m=m, d=7)
+    key = jax.random.PRNGKey(5)
+    shares, ctx = protocol.encode_dataset(cfg, key, x)
+    xq = encode.pad_rows(quantize.quantize_data(x, cfg.lx, p), cfg.K)
+    parts = xq.reshape(cfg.K, -1, x.shape[1])
+    masks = lagrange.draw_masks(key, cfg.T, parts.shape[1:], p)
+    flat = jnp.concatenate([parts, masks]).reshape(cfg.K + cfg.T, -1)
+    U = jnp.asarray(cfg.scheme.encode_matrix, jnp.int32)
+    want = field.matmul(U.T, flat, p).reshape(cfg.N, *parts.shape[1:])
+    assert np.array_equal(np.asarray(shares), np.asarray(want))
+    assert np.array_equal(np.asarray(ctx["xq"]), np.asarray(xq))
+    assert type(ctx["m_padded"]) is int and ctx["m_padded"] == xq.shape[0]
+
+
+def test_train_traces_the_dataset_encode_once():
+    """Two jobs of the same shapes share one compiled encode: the jit's
+    cache grows by one program at the first job and not at the second,
+    while each job still encodes with its own key."""
+    from repro.core.protocol import encode
+    cfg = small_cfg(K=3, N=10)
+    x, y = synthetic.mnist_like(jax.random.PRNGKey(1), m=59, d=11)
+    before = encode._encode_dataset._cache_size()
+    w1, _ = protocol.train(cfg, jax.random.PRNGKey(1), x, y, iters=2)
+    after_first = encode._encode_dataset._cache_size()
+    w2, _ = protocol.train(cfg, jax.random.PRNGKey(2), x, y, iters=2)
+    assert after_first == before + 1
+    assert encode._encode_dataset._cache_size() == after_first
+    assert not np.array_equal(np.asarray(w1), np.asarray(w2))
