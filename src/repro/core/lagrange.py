@@ -91,8 +91,14 @@ def _limb_weights(U: np.ndarray, p: int) -> np.ndarray:
     return L.transpose(0, 2, 1)
 
 
-def combine(U: np.ndarray, flat: jax.Array, p: int) -> jax.Array:
+def combine(U: np.ndarray, flat: jax.Array, p: int,
+            block: tuple[jax.Array, int] | None = None) -> jax.Array:
     """(Uᵀ @ flat) mod p for a host-constant (rows, N) U: (N, E) int32.
+
+    ``block = (i, n)`` gives only the n output rows [i·n, (i+1)·n), the
+    shares of one chip's workers (``i`` may be traced): the same columns
+    of U, sliced from the constant, so each share is computed exactly as
+    in the whole combination.
 
     The encode's contraction is only rows = K+T (or K, or T) deep, so it
     is not a general ``field.matmul`` (DESIGN.md §3, "The encode's narrow
@@ -107,10 +113,18 @@ def combine(U: np.ndarray, flat: jax.Array, p: int) -> jax.Array:
     """
     rows, N = U.shape
     nl = field.n_limbs(p)
+
+    def columns(W, axis):
+        if block is None:
+            return W
+        i, n = block
+        return jax.lax.dynamic_slice_in_dim(W, i * n, n, axis)
+
     if nl * rows * field.LIMB_MASK ** 2 >= min(p, 1 << 24):
-        return field.matmul(jnp.asarray(np.asarray(U).T, jnp.int32), flat, p)
+        Ut = jnp.asarray(np.asarray(U).T, jnp.int32)
+        return field.matmul(columns(Ut, 0), flat, p)
     L = jnp.asarray(_limb_weights(U, p), jnp.bfloat16)       # (nl, N, nl·rows)
-    L = L.reshape(nl, N, nl, rows)
+    L = columns(L.reshape(nl, N, nl, rows), 1)
     shifts = jnp.arange(nl, dtype=jnp.int32)[:, None, None] * field.LIMB_BITS
     X = ((flat[None] >> shifts) & field.LIMB_MASK).astype(jnp.bfloat16)
 
@@ -125,26 +139,30 @@ def combine(U: np.ndarray, flat: jax.Array, p: int) -> jax.Array:
 
 
 def encode(scheme: CodingScheme, x_parts: jax.Array, masks: jax.Array,
-           p: int | None = None) -> jax.Array:
+           p: int | None = None,
+           block: tuple[jax.Array, int] | None = None) -> jax.Array:
     """Encode stacked parts+masks into N shares (Eq. 12).
 
     x_parts: (K, *part_shape) int32 field elements.
     masks:   (T, *part_shape) uniform field elements (the Z_i / V_i).
     Returns shares: (N, *part_shape), each element the (K+T)-term
-    combination ``combine`` computes, exact mod p.
+    combination ``combine`` computes, exact mod p; with ``block = (i, n)``
+    only shares [i·n, (i+1)·n), bit-identical to those rows of the whole.
     """
     p = p or scheme.p
     stacked = jnp.concatenate([x_parts, masks], axis=0) if scheme.T else x_parts
-    return _encode_rows(scheme, stacked, slice(0, scheme.K + scheme.T), p)
+    return _encode_rows(scheme, stacked, slice(0, scheme.K + scheme.T), p,
+                        block)
 
 
 def _encode_rows(scheme: CodingScheme, stacked: jax.Array, rows: slice,
-                 p: int) -> jax.Array:
+                 p: int, block: tuple[jax.Array, int] | None = None
+                 ) -> jax.Array:
     """Shares contributed by a contiguous row-slice of the encode matrix U."""
     part_shape = stacked.shape[1:]
     flat = stacked.reshape(stacked.shape[0], -1)
-    shares = combine(scheme.encode_matrix[rows], flat, p)  # (N, prod(shape))
-    return shares.reshape(scheme.N, *part_shape)
+    shares = combine(scheme.encode_matrix[rows], flat, p, block)
+    return shares.reshape(-1, *part_shape)      # (N or n, *part_shape)
 
 
 def encode_data(scheme: CodingScheme, x_parts: jax.Array,

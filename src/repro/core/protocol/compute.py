@@ -50,26 +50,42 @@ def worker_fn(cfg: CPMLConfig, cbar: jax.Array
     return f
 
 
+def shard_devices(cfg: CPMLConfig) -> int | None:
+    """Devices D on the active mesh's worker axis under backend='shard',
+    each holding a block of N/D shares; None for vmap or with no such mesh.
+    """
+    if cfg.backend != "shard":
+        return None
+    axis = cfg.mesh_axis
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or axis not in mesh.axis_names:
+        return None
+    n_dev = mesh.shape[axis]
+    if cfg.N % n_dev:
+        raise ValueError(
+            f"backend='shard': {n_dev} devices on mesh axis {axis!r} do "
+            f"not divide N={cfg.N} workers; each device evaluates an "
+            f"equal block of N/D shares")
+    return n_dev
+
+
 def all_worker_results(cfg: CPMLConfig, cbar: jax.Array, x_shares: jax.Array,
                        w_shares: jax.Array) -> jax.Array:
-    """(N, mk, d) x (N, d, c, r) -> (N, d, c) worker results."""
+    """(N, mk, d) x (N, d, c, r) -> (N, d, c) worker results.
+
+    Under ``shard`` the dataset shares arrive as the sharded encode left
+    them, N/D on each device (encode.py), and enter shard_map as they are.
+    """
     f = worker_fn(cfg, cbar)
     if cfg.backend == "vmap":
         return jax.vmap(f)(x_shares, w_shares)
     elif cfg.backend == "shard":
         from jax.sharding import PartitionSpec as Pspec
         axis = cfg.mesh_axis
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh.empty or axis not in mesh.axis_names:
+        if shard_devices(cfg) is None:
             raise ValueError(
                 f"backend='shard' needs an active mesh with a {axis!r} axis: "
                 f"run under `with jax.set_mesh(mesh):`")
-        n_dev = mesh.shape[axis]
-        if cfg.N % n_dev:
-            raise ValueError(
-                f"backend='shard': {n_dev} devices on mesh axis {axis!r} do "
-                f"not divide N={cfg.N} workers; each device evaluates an "
-                f"equal block of N/D shares")
 
         def shard_body(xs, ws):
             # this device's block of N/D workers, with no collective

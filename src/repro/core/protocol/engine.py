@@ -57,9 +57,10 @@ class CPMLState:
     m: int                  # number of (unpadded) samples
     mk: int                 # rows per part (padded m / K)
     xq_real: jax.Array      # dequantized dataset (m_padded, d) — loss/oracle
-    xq_parts: jax.Array     # the same, split (K, mk, d) — mini-batch xty
+    xq_parts: jax.Array | None  # the same, split (K, mk, d) — mini-batch
     y: jax.Array            # padded labels, original form (m_padded,)
-    y_parts: jax.Array      # targets split (K, mk, c) real (one-hot if c>1)
+    y_parts: jax.Array | None   # targets split (K, mk, c) real (one-hot if
+    #                             c>1); both None for full-batch jobs
 
 
 def _targets(cfg: CPMLConfig, y: jax.Array) -> jax.Array:
@@ -78,26 +79,48 @@ def setup(cfg: CPMLConfig, key: jax.Array, x: jax.Array, y: jax.Array,
     sharded master group own the encode (cluster/master_group.py) — it must
     be bit-identical to the default, which the group guarantees by drawing
     all randomness at full shape.
+
+    The master's cleartext is held once: ``xq_real``, and its split
+    ``xq_parts`` / ``y_parts`` only where mini-batch rounds read them.
     """
     kx, _ = jax.random.split(key)
     encoder = dataset_encoder or encode.encode_dataset
     with phase("setup.encode_dataset"):
         x_shares, ctx = encoder(cfg, kx, x)
-    xq_real = quantize.dequantize(ctx["xq"], cfg.lx, cfg.p)
+    xq_real = _dequantize(ctx.pop("xq"), cfg.lx, cfg.p)
+    if compute.shard_devices(cfg) is not None:
+        # shares placed by worker are sized to fill the chips: the
+        # quantized copy goes before the step size's transpose is made,
+        # where otherwise the host would dispatch it while both are live
+        jax.block_until_ready(xq_real)
     m_padded = ctx["m_padded"]
     mk = m_padded // cfg.K
     y_pad = jnp.concatenate([y, jnp.zeros(m_padded - y.shape[0], y.dtype)])
     targets = _targets(cfg, y_pad)                       # (m_padded, c)
-    xty = _w_public(cfg, xq_real.T @ targets)            # (d,) or (d, c)
+    xty = _w_public(cfg, _xty(xq_real, targets))         # (d,) or (d, c)
     d = x.shape[1]
     if w0 is None:
         w = jnp.zeros((d,) if cfg.c == 1 else (d, cfg.c), jnp.float32)
     else:
         w = w0
+    parts = cfg.batch_rows is not None
     return CPMLState(
         w=w, x_shares=x_shares, xty=xty, m=x.shape[0], mk=mk,
-        xq_real=xq_real, xq_parts=xq_real.reshape(cfg.K, mk, d),
-        y=y_pad, y_parts=targets.reshape(cfg.K, mk, cfg.c))
+        xq_real=xq_real,
+        xq_parts=xq_real.reshape(cfg.K, mk, d) if parts else None,
+        y=y_pad, y_parts=targets.reshape(cfg.K, mk, cfg.c) if parts else None)
+
+
+# one program, so the dequantized dataset is the only full-size buffer it
+# makes (elementwise and exact: the same values as the eager ops)
+_dequantize = jax.jit(quantize.dequantize, static_argnums=(1, 2))
+
+
+# Xᵀy as one program: the transpose folds into the product, so no
+# transposed copy of the dataset is made.  Its terms are multiples of
+# 2^-lx times 0/1 targets, summed exactly in float32 in any order: the same
+# values as the eager transpose and product.
+_xty = jax.jit(lambda xq_real, targets: xq_real.T @ targets)
 
 
 def _w_internal(cfg: CPMLConfig, w: jax.Array) -> jax.Array:
@@ -467,7 +490,7 @@ def _train_scan(cfg: CPMLConfig, eval_every: int, w0: jax.Array,
                 xty_full: jax.Array, keys: jax.Array, dmats: jax.Array,
                 orders: jax.Array, batch_idx: jax.Array | None,
                 eta: jax.Array, m_int: jax.Array,
-                x_eval: jax.Array, y_eval: jax.Array):
+                x_eval: jax.Array | None, y_eval: jax.Array | None):
     def body(w2, xs):
         t, key, dmat, order, bidx = xs
         w_new = _round(cfg, key, w2, x_shares, xq_parts, y_parts, xty_full,
@@ -499,12 +522,14 @@ def train(cfg: CPMLConfig, key: jax.Array, x: jax.Array, y: jax.Array,
                 eta = lipschitz_eta(state.xq_real)
         with phase("setup.schedule"):
             sched = make_schedule(cfg, kloop, iters, state.mk, survivor_fn)
+        # the evaluation's copy of the real rows only where it is read
+        evals = ((state.xq_real[: state.m], state.y[: state.m])
+                 if eval_every else (None, None))
         w2, metrics = _train_scan(
             cfg, int(eval_every), _w_internal(cfg, state.w), state.x_shares,
             state.xq_parts, state.y_parts, _w_internal(cfg, state.xty),
             sched.keys, sched.decode_mats, sched.orders, sched.batch_idx,
-            *_scale_args(cfg, eta, state),
-            state.xq_real[: state.m], state.y[: state.m])
+            *_scale_args(cfg, eta, state), *evals)
         history: list[dict[str, float]] = []
         if eval_every:
             losses, accs = metrics
@@ -556,11 +581,16 @@ def lipschitz_eta(xq_real: jax.Array) -> float:
     same X, hence the same L."""
     # power iteration — avoids O(d^3) eigendecomposition for large d.
     m, d = xq_real.shape
+    # Xᵀ made once: an eager ``xq_real.T`` in the loop copies the dataset
+    # every step, and the steps are dispatched ahead of the device, so
+    # several copies can be live at once.  The products are the same
+    # programs on the same values.
+    xt = xq_real.T
     v = jnp.ones((d,), jnp.float32) / np.sqrt(d)
     for _ in range(50):
-        v = xq_real.T @ (xq_real @ v)
+        v = xt @ (xq_real @ v)
         v = v / (jnp.linalg.norm(v) + 1e-30)
-    lam = v @ (xq_real.T @ (xq_real @ v))
+    lam = v @ (xt @ (xq_real @ v))
     return float(4.0 * m / lam)
 
 

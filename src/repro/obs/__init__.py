@@ -19,17 +19,22 @@ profiler's host clock, the clock of the device planes in the same trace: a
 only on a decode-matrix cache miss), ``cpml.round_program`` (the cluster
 round), and
 ``cpml.train``, ``cpml.setup.encode_dataset``, ``cpml.setup.step_size``,
-``cpml.setup.schedule`` (a training job).  Device scopes
+``cpml.setup.schedule`` (a training job), and
+``cpml.setup.encode_dataset.block`` around each row block of the sharded
+dataset encode.  Device scopes
 (``jax.named_scope``, in the compiled ops' ``op_name``):
-``cpml_encode_weights``, ``cpml_worker``, ``cpml_decode``.  The on-chip
+``cpml_encode_weights``, ``cpml_worker``, ``cpml_decode``,
+``cpml_encode_dataset``.  ``REGISTRY`` (``metrics.py``) holds the
+process's counters.  The on-chip
 benchmark reads them in its traced run (PERF.md §3), where their cost is
 measured too: with no profiler session an annotation costs a few hundred
 ns.
 """
-from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
+from repro.obs.metrics import (REGISTRY, Counter, Gauge, Histogram,
+                               MetricsRegistry)
 from repro.obs.trace import NULL_RECORDER, NullRecorder, Recorder, Span
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
+    "REGISTRY", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "NULL_RECORDER", "NullRecorder", "Recorder", "Span",
 ]

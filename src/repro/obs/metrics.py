@@ -1,9 +1,10 @@
 """Metrics registry: counters/gauges/histograms with two exporters.
 
-One registry per runner (DESIGN.md §11).  The instruments are deliberately
-minimal — monotone counters, last-value gauges, fixed-bucket histograms —
-because everything heavier (percentiles over full series, waterfalls) comes
-out of the span trace, not the metrics.  Two export formats:
+One registry per runner (DESIGN.md §11), and ``REGISTRY`` for the
+process.  The instruments are deliberately minimal — monotone counters,
+last-value gauges, fixed-bucket histograms — because everything heavier
+(percentiles over full series, waterfalls) comes out of the span trace,
+not the metrics.  Two export formats:
 
   * ``to_prometheus()`` — the textfile exposition format, ready for a
     node-exporter textfile collector (``cpml_cluster --metrics-out``);
@@ -148,6 +149,13 @@ class MetricsRegistry:
         else:
             with open(path, "w") as f:
                 f.write(self.to_prometheus())
+
+
+# The process's own registry, for what the protocol's stages count outside
+# any runner: ``cpml_encode_row_blocks`` (row blocks the dataset encodes
+# dispatched) and ``cpml_share_bytes_per_chip`` (bytes of dataset shares
+# each device holds after the last encode), both from core/protocol/encode.py.
+REGISTRY = MetricsRegistry()
 
 
 def _le(le: float) -> str:

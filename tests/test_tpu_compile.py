@@ -143,3 +143,32 @@ def test_dataset_encode_compiles_for_v5e(one_chip, for_tpu, c, m, d, p):
         _sds((m, d), jnp.float32, one_chip)).compile()
     assert encode.SCOPE_ENCODE_DATASET in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < ENCODE_TEMP_BOUND
+
+
+def test_sharded_dataset_encode_compiles_for_v5e_2x2(topo, for_tpu):
+    """The epsilon cell's dataset encode over a v5e host, (m, d) = (400000,
+    2000) at P30: the quantize leaves no full-size temporary, and each row
+    block's program writes a chip's own 10 shares (2.46 GB) in place with
+    well under 1 GB of temporaries (0.61 GB when written), where the
+    one-program encode would hold all 40 shares on every chip."""
+    cfg = CPMLConfig(**CASE1, p=field.P30, backend="shard")
+    m, d = 400000, 2000
+    mk = -(-m // cfg.K)
+    mesh = auto_mesh((4,), (cfg.mesh_axis,), devices=topo.devices)
+    rep = NamedSharding(mesh, PartitionSpec())
+    mine = NamedSharding(mesh, PartitionSpec(cfg.mesh_axis))
+    i32 = jnp.int32
+    with jax.set_mesh(mesh):
+        prep = encode._quantize_masks.lower(
+            cfg, _sds((2,), jnp.uint32, rep),
+            _sds((m, d), jnp.float32, rep)).compile()
+        block = encode._encode_block.lower(
+            cfg, encode.block_rows(cfg, mk, d),
+            _sds((cfg.N, mk, d), i32, mine), _sds((cfg.K * mk, d), i32, rep),
+            _sds((cfg.T, mk, d), i32, rep), _sds((), i32, rep)).compile()
+    assert prep.memory_analysis().temp_size_in_bytes < 1e8
+    mem = block.memory_analysis()
+    # a chip's own shares, padded to the tile of the layout
+    assert mem.output_size_in_bytes < 1.01 * (cfg.N // 4) * mk * d * 4
+    assert mem.temp_size_in_bytes < 1e9
+    assert encode.SCOPE_ENCODE_DATASET in block.as_text()
