@@ -12,8 +12,11 @@ Backend matrix (DESIGN.md §4):
                  (D must divide N) with zero collectives in the worker step
                  (the paper's key property), then one all_gather plays
                  "send results to master".
-  * use_kernel — routes the per-worker computation through the fused Pallas
-                 kernel (kernels/coded_grad.py) on EITHER backend.
+  * the path  — on a TPU the fused Pallas kernel (kernels/coded_grad.py)
+                 takes every worker's share in one call on either backend;
+                 elsewhere the jnp reference (kernels/ref.py), vmapped.
+                 ``cfg.use_kernel`` True / False forces one; None picks by
+                 platform at trace time.
 """
 from __future__ import annotations
 
@@ -21,7 +24,48 @@ from typing import Callable
 
 import jax
 
+from repro.core import field
 from repro.core.protocol.config import CPMLConfig
+from repro.obs.metrics import REGISTRY
+
+
+def fused(cfg: CPMLConfig) -> bool:
+    """Whether the worker step runs the fused kernel: ``cfg.use_kernel``,
+    or where that is None, whether JAX's default backend is a TPU."""
+    if cfg.use_kernel is not None:
+        return cfg.use_kernel
+    return jax.default_backend() == "tpu"
+
+
+def _count(cfg: CPMLConfig, rows: int) -> None:
+    """One more worker step built, labelled with its path, its digits or
+    limbs and the rows of a share it reads at a time."""
+    REGISTRY.counter("cpml_worker_programs",
+                     "worker steps built, by path",
+                     path="fused" if fused(cfg) else "reference",
+                     nl=field.n_limbs(cfg.p), rows=rows).inc()
+
+
+def workers_fn(cfg: CPMLConfig, cbar: jax.Array
+               ) -> Callable[[jax.Array, jax.Array], jax.Array]:
+    """f(X̃, W̃) for a stack of workers: (n, mk, d), (n, d, c, r) ->
+    (n, d, c)."""
+
+    def f(x_shares: jax.Array, w_shares: jax.Array) -> jax.Array:
+        mk, d = x_shares.shape[1:]
+        if fused(cfg):
+            from repro.kernels import coded_grad
+            _count(cfg, coded_grad.row_block(mk, d))
+            return coded_grad.coded_grad_workers(x_shares, w_shares, cbar,
+                                                 cfg.p)
+        # the unfused jnp path IS the kernel oracle (itself pinned to a
+        # python-int ground truth in test_kernels.py)
+        from repro.kernels import ref
+        _count(cfg, mk)
+        return jax.vmap(lambda x, w: ref.coded_grad_mc_ref(x, w, cbar, cfg.p)
+                        )(x_shares, w_shares)
+
+    return f
 
 
 def worker_fn(cfg: CPMLConfig, cbar: jax.Array
@@ -31,21 +75,12 @@ def worker_fn(cfg: CPMLConfig, cbar: jax.Array
     Legacy binary shape (d, r) is also accepted and returns (d,) — the
     pre-multi-class contract, still used by benchmarks/phases.py.
     """
+    fs = workers_fn(cfg, cbar)
 
     def f(x_share: jax.Array, w_share: jax.Array) -> jax.Array:
         if w_share.ndim == 2:
             return f(x_share, w_share[:, None, :])[:, 0]
-        c = w_share.shape[1]
-        if cfg.use_kernel:
-            from repro.kernels import ops as kernel_ops
-            if c == 1:
-                return kernel_ops.coded_grad(
-                    x_share, w_share[:, 0, :], cbar, cfg.p)[:, None]
-            return kernel_ops.coded_grad_mc(x_share, w_share, cbar, cfg.p)
-        # the unfused jnp path IS the kernel oracle (itself pinned to a
-        # python-int ground truth in test_kernels.py)
-        from repro.kernels import ref
-        return ref.coded_grad_mc_ref(x_share, w_share, cbar, cfg.p)
+        return fs(x_share[None], w_share[None])[0]
 
     return f
 
@@ -76,9 +111,9 @@ def all_worker_results(cfg: CPMLConfig, cbar: jax.Array, x_shares: jax.Array,
     Under ``shard`` the dataset shares arrive as the sharded encode left
     them, N/D on each device (encode.py), and enter shard_map as they are.
     """
-    f = worker_fn(cfg, cbar)
+    f = workers_fn(cfg, cbar)
     if cfg.backend == "vmap":
-        return jax.vmap(f)(x_shares, w_shares)
+        return f(x_shares, w_shares)
     elif cfg.backend == "shard":
         from jax.sharding import PartitionSpec as Pspec
         axis = cfg.mesh_axis
@@ -89,7 +124,7 @@ def all_worker_results(cfg: CPMLConfig, cbar: jax.Array, x_shares: jax.Array,
 
         def shard_body(xs, ws):
             # this device's block of N/D workers, with no collective
-            res = jax.vmap(f)(xs, ws)
+            res = f(xs, ws)
             # "send result back to the master": one collective, results
             # replicated so the (replicated) decode can run everywhere.
             return jax.lax.all_gather(res, axis, axis=0, tiled=True)

@@ -7,8 +7,8 @@ Builds a synthetic classification task, runs the scan-jitted coded engine
 schedule), and reports accuracy against the cleartext quantized baseline.
 ``--backend shard`` spreads the N workers over every device JAX sees, a
 block of N/D coded shares per device (the paper's deployment shape at
-D = N); ``--kernel`` routes the worker step through the fused Pallas
-kernel.
+D = N); the worker step runs the fused Pallas kernel on a TPU and the jnp
+reference elsewhere, and ``--kernel`` forces the kernel.
 """
 from __future__ import annotations
 
@@ -35,7 +35,8 @@ def build_parser() -> argparse.ArgumentParser:
                     help="mini-batch rows per part per round (default: full)")
     ap.add_argument("--backend", choices=("vmap", "shard"), default="vmap")
     ap.add_argument("--kernel", action="store_true",
-                    help="use the fused Pallas worker kernel")
+                    help="force the fused Pallas worker kernel (default: "
+                         "on a TPU only)")
     ap.add_argument("--p30", action="store_true",
                     help="use the 30-bit extended prime (more headroom)")
     ap.add_argument("--drop-workers", type=int, default=0,
@@ -64,7 +65,7 @@ def main(argv: list[str] | None = None) -> int:
     cfg = protocol.CPMLConfig(
         N=args.workers, K=args.parallel, T=args.privacy, r=args.degree,
         c=args.classes, p=field.P30 if args.p30 else field.P,
-        backend=args.backend, use_kernel=args.kernel,
+        backend=args.backend, use_kernel=True if args.kernel else None,
         batch_rows=args.batch_rows)
     drop = args.drop_workers
     assert cfg.N - drop >= cfg.threshold, (

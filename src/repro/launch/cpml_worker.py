@@ -392,9 +392,10 @@ def serve(args) -> int:
                     state["f_fwd"] = jax.jit(lambda xb, ws: xb @ ws)
                     state["f_bwd"] = jax.jit(lambda xb, ws: xb.T @ ws)
                 else:
-                    # worker compute never needs the sharded backend or the
-                    # Pallas kernel: the jnp reference path is the exact
-                    # field-arithmetic spec (DESIGN.md §4), identical mod p.
+                    # worker compute never needs the sharded backend; the
+                    # path is this host's (the fused kernel on a TPU, the
+                    # jnp reference elsewhere), identical mod p (DESIGN.md
+                    # §4).
                     state["protocol"] = "cpml"
                     cfg = CPMLConfig(**p["cfg"])
                     # jit the round evaluation: eager op-by-op dispatch of

@@ -96,7 +96,12 @@ class MetricsRegistry:
                             f"{type(m).__name__}, not {cls.__name__}")
         return m
 
-    def counter(self, name: str, help_: str = "") -> Counter:
+    def counter(self, name: str, help_: str = "", **labels) -> Counter:
+        """``labels`` give one counter per set of values, named
+        ``name{k="v",...}`` as the Prometheus sample is."""
+        if labels:
+            name += "{" + ",".join(f'{k}="{v}"' for k, v in labels.items()) \
+                + "}"
         return self._get(Counter, name, help_)
 
     def gauge(self, name: str, help_: str = "") -> Gauge:
@@ -125,10 +130,14 @@ class MetricsRegistry:
     def to_prometheus(self) -> str:
         """Prometheus textfile exposition format."""
         lines: list[str] = []
+        described = set()
         for name, m in self._metrics.items():
-            if m.help:
-                lines.append(f"# HELP {name} {m.help}")
-            lines.append(f"# TYPE {name} {m.kind}")
+            base = name.split("{")[0]
+            if base not in described:
+                described.add(base)
+                if m.help:
+                    lines.append(f"# HELP {base} {m.help}")
+                lines.append(f"# TYPE {base} {m.kind}")
             if isinstance(m, Histogram):
                 cum = 0
                 for le, c in zip(m.buckets, m.counts):
@@ -154,7 +163,9 @@ class MetricsRegistry:
 # The process's own registry, for what the protocol's stages count outside
 # any runner: ``cpml_encode_row_blocks`` (row blocks the dataset encodes
 # dispatched) and ``cpml_share_bytes_per_chip`` (bytes of dataset shares
-# each device holds after the last encode), both from core/protocol/encode.py.
+# each device holds after the last encode), both from core/protocol/encode.py,
+# and ``cpml_worker_programs{path,nl,rows}`` (worker steps built, by the path
+# taken) from core/protocol/compute.py.
 REGISTRY = MetricsRegistry()
 
 

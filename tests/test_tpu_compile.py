@@ -2,10 +2,10 @@
 
 The TPU compiler refuses what interpret mode accepts: blocks not aligned to
 the tiling, kernels over the fast-memory limit, programs that do not fit the
-device.  These compiles guard the main path's kernels, the jitted
-training scan at paper Case 1 width (N=40, K=13, T=1, r=1,
-(m, d) = (12396, 1568)) and each job's compiled dataset encode at no chip
-time.
+device.  These compiles guard the main path's kernels, the default
+worker step at each benchmark cell's share shapes, the jitted training scan
+at paper Case 1 width (N=40, K=13, T=1, r=1, (m, d) = (12396, 1568)) and
+each job's compiled dataset encode at no chip time.
 
 Only one process at a time may load the TPU library, and it keeps it until
 it exits.  So the topology is described inside a module fixture, never at
@@ -13,6 +13,7 @@ import, and every such compile lives in this one file: under several test
 workers only the worker given this file loads the library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core import field
-from repro.core.protocol import CPMLConfig, encode, engine
+from repro.core.protocol import CPMLConfig, compute, encode, engine
 from repro.kernels import coded_grad, modmatmul
 from repro.launch.mesh import auto_mesh
 
@@ -123,6 +124,85 @@ def test_train_scan_shard_compiles_for_v5e_2x2(topo, for_tpu):
         compiled = engine._train_scan.lower(
             cfg, 0, *_scan_args(cfg, replicated)).compile()
     assert "all-gather" in compiled.as_text()
+
+
+# Each one-chip cell's shares (N, m/K, d) and the epsilon cell's, whose 40
+# shares only a v5e host holds: (c, p) as the cells run them.
+CELLS = {"mnist37": (40, MK, D, 1, field.P),
+         "mnist10-p30": (40, 4616, 784, 10, field.P30),
+         "epsilon-p30": (40, 30770, 2000, 1, field.P30)}
+
+
+def _int32_dots(hlo: str, scope: str) -> list[str]:
+    """The compiled program's int32 dots (or convolutions) under ``scope``."""
+    return [line for line in hlo.splitlines() if scope in line
+            and re.search(r"= s32\[[^=]*\b(dot|convolution)\(", line)]
+
+
+@pytest.mark.parametrize("cell, use_kernel", [
+    ("mnist37", None), ("mnist10-p30", None), ("mnist37", False)],
+    ids=["mnist37", "mnist10-p30", "mnist37-reference"])
+def test_default_worker_step_compiles_for_v5e(one_chip, for_tpu, cell,
+                                              use_kernel):
+    """The worker step as the one-chip cells build it (the scan and the
+    cluster round share it): on a TPU the default is the fused kernel, and
+    no int32 dot is left under the worker's scope; forcing the reference
+    shows what the check would find."""
+    n, mk, d, c, p = CELLS[cell]
+    cfg = CPMLConfig(**CASE1, c=c, p=p, use_kernel=use_kernel)
+
+    def step(cbar, xs, ws):
+        with jax.named_scope(engine.SCOPE_WORKER):
+            return compute.all_worker_results(cfg, cbar, xs, ws)
+
+    hlo = jax.jit(step).lower(
+        _sds((cfg.r + 1,), jnp.int32, one_chip),
+        _sds((n, mk, d), jnp.int32, one_chip),
+        _sds((n, d, c, cfg.r), jnp.int32, one_chip)).compile().as_text()
+    fused = use_kernel is None
+    assert ("tpu_custom_call" in hlo) == fused
+    assert (not _int32_dots(hlo, engine.SCOPE_WORKER)) == fused
+
+
+def test_cluster_round_program_compiles_for_v5e(one_chip, for_tpu):
+    """The cluster cell's per-round program (``engine._round``, the one
+    ``ClusterRunner`` dispatches) at Case 1 width: the fused kernel, no
+    int32 dot under the worker's scope."""
+    cfg = CPMLConfig(**CASE1)
+    R, i32, f32 = cfg.threshold, jnp.int32, jnp.float32
+    hlo = engine._round_jit.lower(
+        cfg, _sds((2,), jnp.uint32, one_chip), _sds((D, 1), f32, one_chip),
+        _sds((cfg.N, MK, D), i32, one_chip), None, None,
+        _sds((D, 1), f32, one_chip), _sds((R, cfg.K), i32, one_chip),
+        _sds((R,), i32, one_chip), None, _sds((), f32, one_chip),
+        _sds((), i32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert not _int32_dots(hlo, engine.SCOPE_WORKER)
+
+
+def test_default_worker_step_shard_compiles_for_v5e_2x2(topo, for_tpu):
+    """The epsilon cell's worker step on a v5e host: each chip's 10 shares
+    of (30770, 2000) through the fused kernel, with no int32 dot and no
+    copy of the shares before it."""
+    n, mk, d, c, p = CELLS["epsilon-p30"]
+    cfg = CPMLConfig(**CASE1, c=c, p=p, backend="shard")
+    mesh = auto_mesh((4,), (cfg.mesh_axis,), devices=topo.devices)
+    rep = NamedSharding(mesh, PartitionSpec())
+    mine = NamedSharding(mesh, PartitionSpec(cfg.mesh_axis))
+
+    def step(cbar, xs, ws):
+        with jax.named_scope(engine.SCOPE_WORKER):
+            return compute.all_worker_results(cfg, cbar, xs, ws)
+
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(step).lower(
+            _sds((cfg.r + 1,), jnp.int32, rep),
+            _sds((n, mk, d), jnp.int32, mine),
+            _sds((n, d, c, cfg.r), jnp.int32, mine)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "all-gather" in hlo
+    assert not _int32_dots(hlo, engine.SCOPE_WORKER)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e8
 
 
 # The compiled dataset encode needed 0.73 GB of temp memory (binary) and
