@@ -7,18 +7,17 @@ Runs paper Algorithm 1 through ``repro.core.protocol`` and the cluster
 runtime at paper Case 1 width: N=40, K=13, T=1, r=1, (m, d) = (12396, 1568),
 synthetic data from fixed seeds.  One chip:
 
-  * worker step — ``all_worker_results`` through ``field.matmul`` and
-    through the Pallas kernel at c=1 and c=10: bit-identical for all N
+  * worker step — ``all_worker_results`` (the Pallas kernel on a TPU) and
+    the vmapped jnp reference at c=1 and c=10: bit-identical for all N
     workers, and equal to a python-int oracle for two of them;
-  * training — the jitted scan with and without the kernel and the
-    per-step ``train_reference``: bit-identical weights, and accuracy
-    within 3 points of the cleartext baseline;
+  * training — the jitted scan and the per-step ``train_reference``:
+    bit-identical weights, and accuracy within 3 points of the cleartext
+    baseline;
   * cluster runtime — ``ClusterRunner`` on the in-process transport under
     lognormal latency == ``train_reference`` replaying its responder trace.
 
 ``--chips 4`` trains with ``backend="shard"`` on a 4-device mesh (10
-shares per chip, with and without the kernel) and with ``backend="vmap"``
-on device 0: bit-identical.
+shares per chip) and with ``backend="vmap"`` on device 0: bit-identical.
 
 Times printed on the way are information, not a benchmark.  The last line
 of stdout is ``{"ok": true, "device": {...}}`` only when every check
@@ -41,7 +40,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
-# paper Case 1 (benchmarks/phases.py:case1) at the paper's (m, d)
+# paper Case 1 (arXiv:1902.00641 §5) at the paper's (m, d)
 N, R_DEG = 40, 1
 M, D = 12396, 1568
 # the paper's Fig. 3 count: after 5 iterations at this width the coded
@@ -93,12 +92,13 @@ def worker_oracle(x_share, w_share, cbar, p: int):
 
 
 def worker_step_phase(cfg, x, c: int, seed: int = 0) -> None:
-    """Kernel vs field.matmul worker results at c heads, plus the oracle."""
+    """Kernel vs reference worker results at c heads, plus the oracle."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core import protocol
+    from repro.kernels import ref
 
     cfg = dataclasses.replace(cfg, c=c)
     kx, kw, kq = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -106,33 +106,31 @@ def worker_step_phase(cfg, x, c: int, seed: int = 0) -> None:
     w = 0.1 * jax.random.normal(kw, (x.shape[1], c))
     w_shares = protocol.encode_weights(cfg, kq, w)        # (N, d, c, r)
     cbar = jnp.asarray(protocol.poly_coeffs(cfg))
+    fns = {"kernel": functools.partial(protocol.all_worker_results, cfg),
+           "field.matmul": lambda cbar, xs, ws: jax.vmap(
+               lambda x, w: ref.coded_grad_mc_ref(x, w, cbar, cfg.p))(xs, ws)}
     results = {}
-    for use_kernel in (False, True):
-        cfg_k = dataclasses.replace(cfg, use_kernel=use_kernel)
-        fn = jax.jit(functools.partial(protocol.all_worker_results, cfg_k))
+    for name, fn in fns.items():
         t0 = time.perf_counter()
-        compiled = fn.lower(cbar, x_shares, w_shares).compile()
+        compiled = jax.jit(fn).lower(cbar, x_shares, w_shares).compile()
         compile_s = time.perf_counter() - t0
-        if use_kernel:
-            # the kernel picks Mosaic exactly when the backend is not the CPU
+        if name == "kernel":
             mosaic = "tpu_custom_call" in compiled.as_text()
-            check(mosaic == (jax.default_backend() != "cpu"),
-                  f"c={c}: kernel path compiled to Mosaic ({mosaic})")
+            check(mosaic, f"c={c}: the worker step compiled to Mosaic")
         _, out = timed(compiled, cbar, x_shares, w_shares)
         steady = statistics.median(
             timed(compiled, cbar, x_shares, w_shares)[0] for _ in range(3))
-        name = "kernel" if use_kernel else "field.matmul"
         print(f"  worker step c={c} [{name}]: compile {compile_s:.3f}s, "
               f"steady {steady:.6f}s for {cfg.N} workers", flush=True)
-        results[use_kernel] = np.asarray(out)
-    check(results[False].shape == (cfg.N, x.shape[1], c),
+        results[name] = np.asarray(out)
+    check(results["kernel"].shape == (cfg.N, x.shape[1], c),
           f"c={c}: worker results shaped (N, d, c)")
-    check(bool((results[False] == results[True]).all()),
+    check(bool((results["kernel"] == results["field.matmul"]).all()),
           f"c={c}: kernel == field.matmul bit for bit, all {cfg.N} workers")
     xs, ws = np.asarray(x_shares), np.asarray(w_shares)
     for i in (0, cfg.N - 1):
         want = worker_oracle(xs[i], ws[i], np.asarray(cbar), cfg.p)
-        check(bool((results[True][i].astype(object) == want).all()),
+        check(bool((results["kernel"][i].astype(object) == want).all()),
               f"c={c}: worker {i} == python-int oracle")
 
 
@@ -152,33 +150,27 @@ def round_timing(cfg, x, y, key) -> None:
             jnp.asarray(order))
     first, _ = timed(run, *args)
     steady = statistics.median(timed(run, *args)[0] for _ in range(3))
-    print(f"  round [kernel={cfg.use_kernel}]: first call {first:.3f}s "
+    print(f"  round: first call {first:.3f}s "
           f"(compile {first - steady:.3f}s), steady {steady:.6f}s/round",
           flush=True)
 
 
 def training_phase(cfg, x, y, key) -> None:
-    """Scan with and without the kernel == train_reference; accuracy."""
+    """Scan == train_reference; accuracy."""
     import numpy as np
 
     from repro.core import protocol
 
-    ws = {}
-    for use_kernel in (True, False):
-        cfg_k = dataclasses.replace(cfg, use_kernel=use_kernel)
-        cold, (w, _) = timed(protocol.train, cfg_k, key, x, y, ITERS)
-        warm, _ = timed(protocol.train, cfg_k, key, x, y, ITERS)
-        print(f"  train {ITERS} iters [kernel={use_kernel}]: cold {cold:.3f}s,"
-              f" warm {warm:.3f}s (setup included)", flush=True)
-        ws[use_kernel] = np.asarray(w)
-        round_timing(cfg_k, x, y, key)
+    cold, (w, _) = timed(protocol.train, cfg, key, x, y, ITERS)
+    warm, _ = timed(protocol.train, cfg, key, x, y, ITERS)
+    print(f"  train {ITERS} iters: cold {cold:.3f}s, warm {warm:.3f}s "
+          f"(setup included)", flush=True)
+    round_timing(cfg, x, y, key)
     w_ref, _ = protocol.train_reference(cfg, key, x, y, ITERS)
     w_ref = np.asarray(w_ref)
     check(bool(np.isfinite(w_ref).all()) and w_ref.shape == (x.shape[1],),
           "weights finite, shaped (d,)")
-    check(bool((ws[True] == ws[False]).all()),
-          "scan with kernel == scan without, bit for bit")
-    check(bool((ws[False] == w_ref).all()),
+    check(bool((np.asarray(w) == w_ref).all()),
           "scan == train_reference, bit for bit")
     wc, xq = protocol.cleartext_baseline(cfg, x, y, ITERS)
     _, acc = protocol.loss_and_accuracy(w_ref, xq, y)
@@ -219,17 +211,14 @@ def shard_phase(cfg, x, y, key, chips: int) -> None:
     print(f"  vmap on {jax.devices()[0]}: cold {cold:.3f}s", flush=True)
     mesh = auto_mesh((chips,), (cfg.mesh_axis,),
                      devices=jax.devices()[:chips])
-    for use_kernel in (False, True):
-        cfg_s = dataclasses.replace(cfg, backend="shard",
-                                    use_kernel=use_kernel)
-        with jax.set_mesh(mesh):
-            cold, (w, _) = timed(protocol.train, cfg_s, key, x, y, ITERS)
-            warm, _ = timed(protocol.train, cfg_s, key, x, y, ITERS)
-        print(f"  shard x{chips} [kernel={use_kernel}]: cold {cold:.3f}s, "
-              f"warm {warm:.3f}s", flush=True)
-        check(bool((np.asarray(w) == np.asarray(wv)).all()),
-              f"shard over {chips} devices ({cfg.N // chips} shares each, "
-              f"kernel={use_kernel}) == vmap, bit for bit")
+    cfg_s = dataclasses.replace(cfg, backend="shard")
+    with jax.set_mesh(mesh):
+        cold, (w, _) = timed(protocol.train, cfg_s, key, x, y, ITERS)
+        warm, _ = timed(protocol.train, cfg_s, key, x, y, ITERS)
+    print(f"  shard x{chips}: cold {cold:.3f}s, warm {warm:.3f}s", flush=True)
+    check(bool((np.asarray(w) == np.asarray(wv)).all()),
+          f"shard over {chips} devices ({cfg.N // chips} shares each) == "
+          f"vmap, bit for bit")
 
 
 def main(argv: list[str] | None = None) -> int:

@@ -11,7 +11,7 @@ arrives (dead worker).  Two properties matter for the runtime:
     re-runs rounds; the cluster must re-observe the same latencies).
   * HEAVY TAILS ON DEMAND: the paper's EC2 speedup comes from not waiting
     for the slow tail; the lognormal-tail and bursty-straggler models
-    reproduce that tail so BENCH_cluster.json can measure the Fig. 5 effect.
+    reproduce that tail so the simulated rounds show the Fig. 5 effect.
 """
 from __future__ import annotations
 

@@ -86,8 +86,8 @@ from repro.runtime.resilience import HeartbeatMonitor, ResilientLoop
 def wait_summary(a) -> dict[str, float]:
     """mean/p50/p95/total of a wait-time series (zeroed when empty).
 
-    The one aggregation both runner.wait_stats and bench_cluster.py report,
-    so BENCH_cluster.json and live stats can never disagree on keys.  An
+    The one aggregation both ClusterRunner and MPCClusterRunner report in
+    wait_stats, so their keys can never disagree.  An
     EMPTY series — no completed rounds, or an all-starved trace — returns a
     well-formed all-zero summary: numpy would warn and NaN on a mean over
     nothing, and inf placeholders poison downstream ratio math (inf/inf)
@@ -1102,8 +1102,8 @@ class ClusterRunner:
             # and heartbeats that landed between rounds
             stats["wire_totals"] = {k: float(v)
                                     for k, v in wire_totals().items()}
-        # elastic membership summary (BENCH_cluster.json rides these):
-        # epoch 0 / joins 0 / leaves 0 on a fixed-membership run
+        # elastic membership summary: epoch 0 / joins 0 / leaves 0 on a
+        # fixed-membership run
         trans = self.membership.transitions
         stats["membership"] = {
             "epoch": float(self.membership.epoch),

@@ -15,8 +15,7 @@ Backend matrix (DESIGN.md §4):
   * the path  — on a TPU the fused Pallas kernel (kernels/coded_grad.py)
                  takes every worker's share in one call on either backend;
                  elsewhere the jnp reference (kernels/ref.py), vmapped.
-                 ``cfg.use_kernel`` True / False forces one; None picks by
-                 platform at trace time.
+                 The platform picks it at trace time (``fused``).
 """
 from __future__ import annotations
 
@@ -29,11 +28,9 @@ from repro.core.protocol.config import CPMLConfig
 from repro.obs.metrics import REGISTRY
 
 
-def fused(cfg: CPMLConfig) -> bool:
-    """Whether the worker step runs the fused kernel: ``cfg.use_kernel``,
-    or where that is None, whether JAX's default backend is a TPU."""
-    if cfg.use_kernel is not None:
-        return cfg.use_kernel
+def fused() -> bool:
+    """Whether the worker step runs the fused kernel: whether JAX's default
+    backend is a TPU."""
     return jax.default_backend() == "tpu"
 
 
@@ -42,7 +39,7 @@ def _count(cfg: CPMLConfig, rows: int) -> None:
     limbs and the rows of a share it reads at a time."""
     REGISTRY.counter("cpml_worker_programs",
                      "worker steps built, by path",
-                     path="fused" if fused(cfg) else "reference",
+                     path="fused" if fused() else "reference",
                      nl=field.n_limbs(cfg.p), rows=rows).inc()
 
 
@@ -53,7 +50,7 @@ def workers_fn(cfg: CPMLConfig, cbar: jax.Array
 
     def f(x_shares: jax.Array, w_shares: jax.Array) -> jax.Array:
         mk, d = x_shares.shape[1:]
-        if fused(cfg):
+        if fused():
             from repro.kernels import coded_grad
             _count(cfg, coded_grad.row_block(mk, d))
             return coded_grad.coded_grad_workers(x_shares, w_shares, cbar,
@@ -70,16 +67,10 @@ def workers_fn(cfg: CPMLConfig, cbar: jax.Array
 
 def worker_fn(cfg: CPMLConfig, cbar: jax.Array
               ) -> Callable[[jax.Array, jax.Array], jax.Array]:
-    """f(X̃, W̃) for ONE worker. (mk, d), (d, c, r) -> (d, c).
-
-    Legacy binary shape (d, r) is also accepted and returns (d,) — the
-    pre-multi-class contract, still used by benchmarks/phases.py.
-    """
+    """f(X̃, W̃) for ONE worker. (mk, d), (d, c, r) -> (d, c)."""
     fs = workers_fn(cfg, cbar)
 
     def f(x_share: jax.Array, w_share: jax.Array) -> jax.Array:
-        if w_share.ndim == 2:
-            return f(x_share, w_share[:, None, :])[:, 0]
         return fs(x_share[None], w_share[None])[0]
 
     return f

@@ -32,7 +32,6 @@ class CPMLConfig:
     p: int = field.P
     backend: str = "vmap"   # "vmap" | "shard"
     mesh_axis: str = "workers"
-    use_kernel: bool | None = None  # None: the fused kernel on a TPU only
     batch_rows: int | None = None   # rows per part per round (None = full)
 
     def __post_init__(self):

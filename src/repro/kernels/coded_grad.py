@@ -217,13 +217,6 @@ def coded_grad_workers(x: jax.Array, w: jax.Array, cbar: jax.Array,
     return jnp.transpose(out[:, :c], (0, 2, 1))
 
 
-def coded_grad(x: jax.Array, w: jax.Array, cbar: jax.Array,
-               p: int = field.P, interpret: bool | None = None) -> jax.Array:
-    """One worker, one head: x (mk, d), w (d, r), cbar (r+1,) -> (d,)."""
-    assert x.ndim == 2 and w.ndim == 2 and x.shape[1] == w.shape[0]
-    return coded_grad_mc(x, w[:, None, :], cbar, p, interpret)[:, 0]
-
-
 def coded_grad_mc(x: jax.Array, w: jax.Array, cbar: jax.Array,
                   p: int = field.P, interpret: bool | None = None
                   ) -> jax.Array:

@@ -1,7 +1,8 @@
-"""Pure-jnp oracles for the Pallas kernels (the correctness ground truth).
+"""Pure-jnp oracle for the fused worker kernel (the correctness ground
+truth), and the worker step off a TPU.
 
-These are deliberately written via the canonical repro.core.field spec (which
-tests separately against numpy int64), NOT by sharing code with the kernels.
+It is deliberately written via the canonical repro.core.field spec (which
+tests separately against numpy int64), NOT by sharing code with the kernel.
 """
 from __future__ import annotations
 
@@ -9,19 +10,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import field, sigmoid_poly
-
-
-def modmatmul_ref(a: jax.Array, b: jax.Array, p: int = field.P) -> jax.Array:
-    """(a @ b) mod p — chunked int32 limb matmul (field.matmul spec)."""
-    return field.matmul(a, b, p)
-
-
-def coded_grad_ref(x: jax.Array, w: jax.Array, cbar: jax.Array,
-                   p: int = field.P) -> jax.Array:
-    """X̃ᵀ ḡ(X̃, W̃) mod p via the unfused field ops (paper Eq. 20)."""
-    xw = field.matmul(x, w, p)                       # (mk, r)
-    s = sigmoid_poly.gbar_field(xw, cbar.astype(jnp.int32), p)  # (mk,)
-    return field.matmul(x.T, s[:, None], p)[:, 0]    # (d,)
 
 
 def coded_grad_mc_ref(x: jax.Array, w: jax.Array, cbar: jax.Array,

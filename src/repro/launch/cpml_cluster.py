@@ -250,8 +250,8 @@ def local_socket_cluster(n_workers: int, *, port: int = 0,
 
     Yields the master ``SocketTransport`` once every worker has connected
     and HELLOed.  On exit the worker processes are terminated and the
-    transport closed.  Reused by benchmarks/bench_socket.py and the slow
-    socket tests, so every consumer launches workers the same way.
+    transport closed.  Reused by the slow socket tests, so every consumer
+    launches workers the same way.
     ``wire_version=1`` forces the legacy wire format on the master AND every
     spawned worker (the v1 baseline for byte-for-byte comparison).
 

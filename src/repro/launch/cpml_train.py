@@ -8,7 +8,7 @@ schedule), and reports accuracy against the cleartext quantized baseline.
 ``--backend shard`` spreads the N workers over every device JAX sees, a
 block of N/D coded shares per device (the paper's deployment shape at
 D = N); the worker step runs the fused Pallas kernel on a TPU and the jnp
-reference elsewhere, and ``--kernel`` forces the kernel.
+reference elsewhere.
 """
 from __future__ import annotations
 
@@ -34,9 +34,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--batch-rows", type=int, default=None,
                     help="mini-batch rows per part per round (default: full)")
     ap.add_argument("--backend", choices=("vmap", "shard"), default="vmap")
-    ap.add_argument("--kernel", action="store_true",
-                    help="force the fused Pallas worker kernel (default: "
-                         "on a TPU only)")
     ap.add_argument("--p30", action="store_true",
                     help="use the 30-bit extended prime (more headroom)")
     ap.add_argument("--drop-workers", type=int, default=0,
@@ -65,15 +62,14 @@ def main(argv: list[str] | None = None) -> int:
     cfg = protocol.CPMLConfig(
         N=args.workers, K=args.parallel, T=args.privacy, r=args.degree,
         c=args.classes, p=field.P30 if args.p30 else field.P,
-        backend=args.backend, use_kernel=True if args.kernel else None,
-        batch_rows=args.batch_rows)
+        backend=args.backend, batch_rows=args.batch_rows)
     drop = args.drop_workers
     assert cfg.N - drop >= cfg.threshold, (
         f"dropping {drop} of N={cfg.N} leaves fewer than the recovery "
         f"threshold {cfg.threshold}")
     print(f"CPML: N={cfg.N} K={cfg.K} T={cfg.T} r={cfg.r} c={cfg.c} "
           f"threshold={cfg.threshold} backend={cfg.backend} "
-          f"kernel={cfg.use_kernel} batch_rows={cfg.batch_rows}")
+          f"batch_rows={cfg.batch_rows}")
 
     key = jax.random.PRNGKey(args.seed)
     if cfg.c == 1:
@@ -122,7 +118,6 @@ def main(argv: list[str] | None = None) -> int:
             json.dump({"config": {"N": cfg.N, "K": cfg.K, "T": cfg.T,
                                   "r": cfg.r, "c": cfg.c,
                                   "backend": cfg.backend,
-                                  "use_kernel": cfg.use_kernel,
                                   "batch_rows": cfg.batch_rows},
                        "seconds": dt, "history": hist,
                        "acc_coded": float(acc),

@@ -19,7 +19,7 @@ only WITHIN a process and are never compared across clock domains.
 ``NullRecorder`` is the off-by-default path: every method is a constant
 no-op (shared singleton context manager, no allocation, no clock read), so
 instrumented code costs next to nothing when tracing is off.
-benchmarks/bench_cluster.py checks that a live recorder never advances the
+tests/test_obs.py checks that a live recorder never advances the
 simulated clock and leaves the weights bit-identical; what tracing costs in
 wall time is measured on the chip (PERF.md).
 

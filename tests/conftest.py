@@ -25,3 +25,19 @@ def exact_modmatmul(a, b, p):
     ao = np.asarray(a).astype(object)
     bo = np.asarray(b).astype(object)
     return (ao @ bo) % p
+
+
+@pytest.fixture()
+def worker_path(request, monkeypatch):
+    """The worker step's path, forced for one test (indirect parameter:
+    "fused" or "reference"; None leaves it to the platform, as everywhere
+    else).  Jit caches are cleared on entry and exit, so a program traced
+    under one path is never served to the other."""
+    from repro.core.protocol import compute
+    path = request.param
+    assert path in (None, "fused", "reference"), path
+    jax.clear_caches()
+    if path is not None:
+        monkeypatch.setattr(compute, "fused", lambda: path == "fused")
+    yield path
+    jax.clear_caches()

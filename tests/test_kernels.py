@@ -1,10 +1,9 @@
-"""Pallas kernel sweeps vs the pure-jnp ref.py oracles (interpret=True).
+"""Pallas kernel sweeps vs the pure-jnp ref.py oracle (interpret=True).
 
 Randomized (hypothesis) coverage lives in test_kernels_properties.py behind
 ``pytest.importorskip`` — hypothesis is an optional dev dependency
 (DESIGN.md §8); this module is fully deterministic.
 """
-import dataclasses
 import os
 import subprocess
 import sys
@@ -15,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import field, protocol, sigmoid_poly
-from repro.kernels import coded_grad, ops, ref
+from repro.kernels import coded_grad, modmatmul, ref
 from conftest import exact_modmatmul
 
 PRIMES = [field.P, field.P30]
@@ -30,7 +29,7 @@ def test_modmatmul_shapes(p, shape, rng):
     M, K, N = shape
     a = jnp.asarray(rng.integers(0, p, (M, K)), jnp.int32)
     b = jnp.asarray(rng.integers(0, p, (K, N)), jnp.int32)
-    got = np.asarray(ops.modmatmul(a, b, p, use_pallas=True)).astype(object)
+    got = np.asarray(modmatmul.modmatmul(a, b, p)).astype(object)
     want = exact_modmatmul(a, b, p)
     assert (got == want).all(), f"mismatch at {shape} p={p}"
 
@@ -40,7 +39,7 @@ def test_modmatmul_extreme_values(p):
     """All entries p-1 — worst case for limb overflow."""
     a = jnp.full((32, 512), p - 1, jnp.int32)
     b = jnp.full((512, 16), p - 1, jnp.int32)
-    got = np.asarray(ops.modmatmul(a, b, p, use_pallas=True)).astype(object)
+    got = np.asarray(modmatmul.modmatmul(a, b, p)).astype(object)
     assert (got == exact_modmatmul(a, b, p)).all()
 
 
@@ -51,7 +50,7 @@ def test_modmatmul_odd_shapes_deterministic(seed):
     m, k, n = rng.integers(1, 80), rng.integers(1, 120), rng.integers(1, 60)
     a = jnp.asarray(rng.integers(0, field.P, (m, k)), jnp.int32)
     b = jnp.asarray(rng.integers(0, field.P, (k, n)), jnp.int32)
-    got = np.asarray(ops.modmatmul(a, b, use_pallas=True)).astype(object)
+    got = np.asarray(modmatmul.modmatmul(a, b)).astype(object)
     assert (got == exact_modmatmul(a, b, field.P)).all()
 
 
@@ -59,11 +58,13 @@ def test_modmatmul_odd_shapes_deterministic(seed):
 @pytest.mark.parametrize("mk,d,r", [(64, 32, 1), (300, 64, 2), (257, 96, 3),
                                     (16, 8, 1), (130, 200, 1)])
 def test_coded_grad_fused(p, mk, d, r, rng):
+    """One binary head: the kernel == the unfused oracle on (d, 1, r)."""
     x = jnp.asarray(rng.integers(0, p, (mk, d)), jnp.int32)
-    w = jnp.asarray(rng.integers(0, p, (d, r)), jnp.int32)
+    w = jnp.asarray(rng.integers(0, p, (d, 1, r)), jnp.int32)
     cbar = jnp.asarray(sigmoid_poly.quantized_coeffs(r, 2, 4, 6, p), jnp.int32)
-    got = ops.coded_grad(x, w, cbar, p, use_pallas=True)
-    want = ref.coded_grad_ref(x, w, cbar, p)
+    got = coded_grad.coded_grad_mc(x, w, cbar, p)
+    want = ref.coded_grad_mc_ref(x, w, cbar, p)
+    assert got.shape == (d, 1)
     assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -72,27 +73,27 @@ def test_coded_grad_fused(p, mk, d, r, rng):
                                       (17, 8, 2, 3), (131, 200, 10, 1)])
 def test_coded_grad_multiclass_fused(p, mk, d, c, r, rng):
     """Multi-head kernel == unfused oracle, and head cls of the (d, c)
-    result == the binary kernel run on that head's weight column alone."""
+    result == the kernel run on that head's weight column alone."""
     x = jnp.asarray(rng.integers(0, p, (mk, d)), jnp.int32)
     w = jnp.asarray(rng.integers(0, p, (d, c, r)), jnp.int32)
     cbar = jnp.asarray(sigmoid_poly.quantized_coeffs(r, 2, 4, 6, p), jnp.int32)
-    got = ops.coded_grad_mc(x, w, cbar, p, use_pallas=True)
+    got = coded_grad.coded_grad_mc(x, w, cbar, p)
     want = ref.coded_grad_mc_ref(x, w, cbar, p)
     assert np.array_equal(np.asarray(got), np.asarray(want))
     for cls in (0, c - 1):
-        head = ops.coded_grad(x, w[:, cls, :], cbar, p, use_pallas=True)
-        assert np.array_equal(np.asarray(got[:, cls]), np.asarray(head))
+        head = coded_grad.coded_grad_mc(x, w[:, cls:cls + 1, :], cbar, p)
+        assert np.array_equal(np.asarray(got[:, cls]), np.asarray(head[:, 0]))
 
 
 def test_ref_oracle_against_numpy(rng):
     """ref.py itself is validated against python-int ground truth."""
     p = field.P
     x = jnp.asarray(rng.integers(0, p, (60, 24)), jnp.int32)
-    w = jnp.asarray(rng.integers(0, p, (24, 2)), jnp.int32)
+    w = jnp.asarray(rng.integers(0, p, (24, 1, 2)), jnp.int32)
     cbar = jnp.asarray(sigmoid_poly.quantized_coeffs(2, 2, 4, 6, p), jnp.int32)
-    got = np.asarray(ref.coded_grad_ref(x, w, cbar, p)).astype(object)
+    got = np.asarray(ref.coded_grad_mc_ref(x, w, cbar, p))[:, 0].astype(object)
     xo = np.asarray(x).astype(object)
-    wo = np.asarray(w).astype(object)
+    wo = np.asarray(w)[:, 0].astype(object)
     z = (xo @ wo) % p
     s = (int(cbar[0]) + int(cbar[1]) * z[:, 0] + int(cbar[2]) * z[:, 0] * z[:, 1]) % p
     want = (xo.T @ s) % p
@@ -101,12 +102,11 @@ def test_ref_oracle_against_numpy(rng):
 
 def test_block_shape_invariance(rng):
     """Kernel output independent of BlockSpec tiling choices."""
-    from repro.kernels import modmatmul as mm
     p = field.P
     a = jnp.asarray(rng.integers(0, p, (100, 200)), jnp.int32)
     b = jnp.asarray(rng.integers(0, p, (200, 70)), jnp.int32)
-    outs = [np.asarray(mm.modmatmul(a, b, p, bm=bm, bn=bn, bk=bk,
-                                    interpret=True))
+    outs = [np.asarray(modmatmul.modmatmul(a, b, p, bm=bm, bn=bn, bk=bk,
+                                           interpret=True))
             for bm, bn, bk in [(32, 32, 64), (128, 128, 256), (16, 64, 32)]]
     assert all(np.array_equal(outs[0], o) for o in outs[1:])
 
@@ -197,19 +197,27 @@ def test_coded_grad_edge_values(p, rng, monkeypatch):
     assert (got.astype(object) == worker_oracle(x[0], w[0], cbar, p)).all()
 
 
+@pytest.mark.parametrize("worker_path", ["fused", "reference"],
+                         indirect=True)
 @pytest.mark.parametrize("c", [1, 3])
-def test_all_worker_results_fused_equals_reference_vmap(c, rng):
-    """N workers through all_worker_results: the fused kernel, one call
-    for the stack, == the vmapped reference."""
+def test_all_worker_results_fused_equals_reference_vmap(c, worker_path,
+                                                        rng):
+    """N workers through all_worker_results on either path (the fused
+    kernel takes the stack in one call) == the vmapped reference, and the
+    path taken is counted."""
+    from repro.obs.metrics import REGISTRY
     cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1, c=c)
     x = jnp.asarray(rng.integers(0, cfg.p, (cfg.N, 40, 24)), jnp.int32)
     w = jnp.asarray(rng.integers(0, cfg.p, (cfg.N, 24, c, 1)), jnp.int32)
     cbar = jnp.asarray(protocol.poly_coeffs(cfg), jnp.int32)
-    out = {k: np.asarray(protocol.all_worker_results(
-        dataclasses.replace(cfg, use_kernel=k), cbar, x, w))
-        for k in (True, False)}
-    assert out[True].shape == (cfg.N, 24, c)
-    assert np.array_equal(out[True], out[False])
+    label = 'cpml_worker_programs{path="%s",nl="3",rows="40"}' % worker_path
+    before = REGISTRY.counter(label).value
+    got = np.asarray(protocol.all_worker_results(cfg, cbar, x, w))
+    assert REGISTRY.counter(label).value == before + 1
+    want = np.asarray(jax.vmap(
+        lambda xi, wi: ref.coded_grad_mc_ref(xi, wi, cbar, cfg.p))(x, w))
+    assert got.shape == (cfg.N, 24, c)
+    assert np.array_equal(got, want)
 
 
 def test_all_worker_results_fused_shard_on_four_devices():
@@ -221,9 +229,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import protocol
+from repro.core.protocol import compute
 from repro.launch.mesh import auto_mesh
-cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1, c=2, use_kernel=True,
-                          backend="shard")
+compute.fused = lambda: True
+cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1, c=2, backend="shard")
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.integers(0, cfg.p, (cfg.N, 40, 24)), jnp.int32)
 w = jnp.asarray(rng.integers(0, cfg.p, (cfg.N, 24, 2, 1)), jnp.int32)
@@ -231,7 +240,9 @@ cbar = jnp.asarray(protocol.poly_coeffs(cfg), jnp.int32)
 mesh = auto_mesh((4,), (cfg.mesh_axis,), devices=jax.devices()[:4])
 with jax.set_mesh(mesh):
     got = protocol.all_worker_results(cfg, cbar, x, w)
-ref_cfg = dataclasses.replace(cfg, backend="vmap", use_kernel=False)
+compute.fused = lambda: False
+jax.clear_caches()
+ref_cfg = dataclasses.replace(cfg, backend="vmap")
 want = protocol.all_worker_results(ref_cfg, cbar, x, w)
 assert len(jax.devices()) == 4
 print("SHARD_FUSED_OK", bool(np.array_equal(np.asarray(got), np.asarray(want))))
@@ -244,19 +255,17 @@ print("SHARD_FUSED_OK", bool(np.array_equal(np.asarray(got), np.asarray(want))))
     assert "SHARD_FUSED_OK True" in out.stdout, out.stdout + out.stderr[-4000:]
 
 
-@pytest.mark.parametrize("backend, use_kernel, want", [
-    ("cpu", None, False), ("tpu", None, True), ("gpu", None, False),
-    ("cpu", True, True), ("tpu", False, False)])
-def test_worker_path_resolves_from_the_platform(monkeypatch, backend,
-                                                use_kernel, want):
-    """use_kernel=None takes the fused kernel on a TPU and the reference
-    elsewhere; True and False force one.  The path is counted, labelled,
-    when a worker step is built."""
+@pytest.mark.parametrize("backend, want", [
+    ("cpu", False), ("tpu", True), ("gpu", False)])
+def test_worker_path_resolves_from_the_platform(monkeypatch, backend, want):
+    """The worker step takes the fused kernel on a TPU and the reference
+    elsewhere.  The path is counted, labelled, when a worker step is
+    built."""
     from repro.core.protocol import compute
     from repro.obs.metrics import REGISTRY
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1, use_kernel=use_kernel)
-    assert compute.fused(cfg) is want
+    cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1)
+    assert compute.fused() is want
     label = 'cpml_worker_programs{path="%s",nl="3",rows="40"}' % (
         "fused" if want else "reference")
     before = REGISTRY.counter(label).value

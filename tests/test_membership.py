@@ -23,6 +23,7 @@ from repro.cluster import (
 )
 from repro.core import protocol
 from repro.data import synthetic
+from repro.obs.trace import Recorder
 from repro.runtime.resilience import HeartbeatMonitor
 
 
@@ -176,9 +177,10 @@ def test_elastic_leave_with_spare_replacement_bit_identical(binary_data):
     cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1)        # threshold 7
     lat = DeadWorkerLatency(DeterministicLatency(base=1.0, skew=0.1),
                             deaths={2: 3})
+    recorder = Recorder()
     runner = ClusterRunner(cfg, jax.random.PRNGKey(7), x, y, lat,
                            heartbeat_timeout_s=4.0, round_timeout_s=60.0,
-                           spares=1)
+                           spares=1, recorder=recorder)
     assert runner.cfg.N == 9                 # extended; threshold unchanged
     assert runner.cfg.threshold == cfg.threshold
     w = runner.run(16)
@@ -198,6 +200,10 @@ def test_elastic_leave_with_spare_replacement_bit_identical(binary_data):
     stats = runner.wait_stats()
     assert stats["membership"]["leaves"] == 1.0
     assert stats["membership"]["joins"] == 1.0
+    # the transitions are on the flight recorder's trace, at their fence
+    spans = recorder.find("membership_transition")
+    assert spans and {int(s.args["round"]) for s in spans} == {
+        tr.round for tr in ms.transitions}
 
     w_ref, _ = protocol.train_reference(runner.cfg, jax.random.PRNGKey(7),
                                         x, y, iters=16,

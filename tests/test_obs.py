@@ -381,6 +381,11 @@ def test_socket_worker_spans_arrive_over_v2_wire(socket_data):
     assert sum(r["critical_path"] for r in round_summaries(rec)) == \
         pytest.approx(runner.wait_stats()["critical_path"]["total"],
                       rel=1e-9)
+    # and tracing changed no bit of the training
+    w_ref, _ = protocol.train_reference(
+        runner.cfg, jax.random.PRNGKey(7), x, y, iters=5,
+        survivor_fn=runner.survivor_fn())
+    assert (np.asarray(w) == np.asarray(w_ref)).all()
 
 
 @pytest.mark.slow

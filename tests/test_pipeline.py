@@ -36,6 +36,7 @@ from repro.cluster import (
     LognormalTailLatency,
     RoundContext,
     RoundPrefetcher,
+    make_latency,
 )
 from repro.core import protocol
 from repro.core.protocol import decode, encode, engine
@@ -256,15 +257,19 @@ def test_prefetcher_close_joins_thread():
 # ClusterRunner pipeline modes: bit-identity, all modes, dead worker
 # ---------------------------------------------------------------------------
 
-def test_pipeline_modes_bit_identical_under_stragglers(binary_data):
+@pytest.mark.parametrize("latency", [
+    lambda: LognormalTailLatency(seed=3, tail_prob=0.3, tail_scale=25.0),
+    lambda: make_latency("bursty", seed=3)], ids=["lognormal", "bursty"])
+def test_pipeline_modes_bit_identical_under_stragglers(binary_data, latency):
     """Every pipeline mode == train_reference on the observed trace, and
     (order-independent latencies) all modes observe the SAME trace and
-    produce the SAME weights as the sequential engine."""
+    produce the SAME weights as the sequential engine, under heavy tails
+    and under multi-round straggler bursts."""
     x, y = binary_data
     cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1)
     ws, traces = {}, {}
     for mode in PIPELINE_MODES:
-        lat = LognormalTailLatency(seed=3, tail_prob=0.3, tail_scale=25.0)
+        lat = latency()
         runner = ClusterRunner(cfg, jax.random.PRNGKey(7), x, y, lat,
                                pipeline=mode,
                                encode_cost_s=0.2, decode_cost_s=0.1)

@@ -93,15 +93,23 @@ def test_too_few_survivors(dataset):
                       survivors=np.arange(6))
 
 
-def test_kernel_path_equals_jnp_path(dataset):
+@pytest.mark.parametrize("worker_path", ["fused"], indirect=True)
+def test_kernel_path_equals_jnp_path(dataset, worker_path, monkeypatch):
+    """One step on the fused kernel == the same step on the jnp
+    reference."""
+    from repro.core.protocol import compute
     x, y = dataset
-    c1 = small_cfg(use_kernel=False)
-    c2 = small_cfg(use_kernel=True)
-    s1 = protocol.setup(c1, jax.random.PRNGKey(0), x, y)
-    s2 = protocol.setup(c2, jax.random.PRNGKey(0), x, y)
-    w1 = protocol.step(c1, jax.random.PRNGKey(1), s1, 0.5).w
-    w2 = protocol.step(c2, jax.random.PRNGKey(1), s2, 0.5).w
-    assert np.allclose(np.asarray(w1), np.asarray(w2), atol=1e-7)
+    cfg = small_cfg()
+
+    def one_step():
+        s = protocol.setup(cfg, jax.random.PRNGKey(0), x, y)
+        return np.asarray(protocol.step(cfg, jax.random.PRNGKey(1), s, 0.5).w)
+
+    w_fused = one_step()
+    monkeypatch.setattr(compute, "fused", lambda: False)
+    jax.clear_caches()
+    w_ref = one_step()
+    assert np.allclose(w_ref, w_fused, atol=1e-7)
 
 
 def test_r2_polynomial(dataset):

@@ -40,14 +40,15 @@ def _clear_field_subgradients(cfg, xq_parts_field, wbar):
     return jnp.stack(out)
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("worker_path", ["reference", "fused"], indirect=True)
 @pytest.mark.parametrize("pattern", [np.arange(8),
                                      np.array([7, 5, 3, 1, 0, 2, 4])])
-def test_multiclass_gradient_exact_over_field(dataset, use_kernel, pattern):
+def test_multiclass_gradient_exact_over_field(dataset, worker_path, pattern):
     """c=3 coded step decodes the EXACT field sub-gradients of the
-    cleartext quantized baseline, for any valid survivor pattern."""
+    cleartext quantized baseline, for any valid survivor pattern, on
+    either worker path."""
     x, y = dataset
-    cfg = mc_cfg(use_kernel=use_kernel)
+    cfg = mc_cfg()
     kx, kw = jax.random.split(jax.random.PRNGKey(0))
     x_shares, ctx = protocol.encode_dataset(cfg, kx, x)
     d = x.shape[1]

@@ -27,10 +27,10 @@ def rotating_survivors(n):
     return lambda t: np.roll(np.arange(n), t)
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_scan_bit_identical_binary(binary_data, use_kernel):
+@pytest.mark.parametrize("worker_path", ["reference", "fused"], indirect=True)
+def test_scan_bit_identical_binary(binary_data, worker_path):
     x, y = binary_data
-    cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1, use_kernel=use_kernel)
+    cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1)
     kw = dict(iters=12, survivor_fn=rotating_survivors(cfg.N), eval_every=6)
     w1, h1 = protocol.train(cfg, jax.random.PRNGKey(7), x, y, **kw)
     w2, h2 = protocol.train_reference(cfg, jax.random.PRNGKey(7), x, y, **kw)
@@ -43,10 +43,10 @@ def test_scan_bit_identical_binary(binary_data, use_kernel):
         assert np.isclose(a["acc"], b["acc"], atol=1e-6)
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_scan_bit_identical_multiclass(mc_data, use_kernel):
+@pytest.mark.parametrize("worker_path", ["reference", "fused"], indirect=True)
+def test_scan_bit_identical_multiclass(mc_data, worker_path):
     x, y = mc_data
-    cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1, c=3, use_kernel=use_kernel)
+    cfg = protocol.CPMLConfig(N=8, K=2, T=1, r=1, c=3)
     w1, _ = protocol.train(cfg, jax.random.PRNGKey(7), x, y, iters=10,
                            survivor_fn=rotating_survivors(cfg.N))
     w2, _ = protocol.train_reference(cfg, jax.random.PRNGKey(7), x, y,

@@ -95,6 +95,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import protocol
+from repro.core.protocol import compute
 from repro.data import synthetic
 from repro.launch.mesh import auto_mesh
 
@@ -112,8 +113,9 @@ assert np.allclose(np.asarray(wv), np.asarray(ws), atol=1e-6), \
 # scan engine == per-step reference loop, bit-identical, on the shard
 # backend — with and without the fused worker kernel (acceptance matrix).
 for kern in (False, True):
-    cfgk = protocol.CPMLConfig(N=8, K=2, T=1, r=1, c=3, backend="shard",
-                               use_kernel=kern)
+    compute.fused = lambda: kern
+    jax.clear_caches()
+    cfgk = protocol.CPMLConfig(N=8, K=2, T=1, r=1, c=3, backend="shard")
     xm, ym = synthetic.multiclass_mnist_like(jax.random.PRNGKey(2), m=240,
                                              d=24, c=3)
     with jax.set_mesh(mesh):

@@ -102,14 +102,15 @@ def _scan_args(cfg: CPMLConfig, sharding):
             _sds((M, D), f32, sharding), _sds((M,), f32, sharding))
 
 
-@pytest.mark.parametrize("use_kernel", [False, True],
-                         ids=["field-matmul", "kernel"])
-def test_train_scan_compiles_for_v5e(one_chip, for_tpu, use_kernel):
+@pytest.mark.parametrize("worker_path", ["reference", "fused"],
+                         ids=["field-matmul", "kernel"], indirect=True)
+def test_train_scan_compiles_for_v5e(one_chip, for_tpu, worker_path):
     """The whole jitted training scan on one chip, N shares under vmap."""
-    cfg = CPMLConfig(**CASE1, use_kernel=use_kernel)
+    cfg = CPMLConfig(**CASE1)
     compiled = engine._train_scan.lower(
         cfg, 0, *_scan_args(cfg, one_chip)).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+    assert ("tpu_custom_call" in compiled.as_text()) == (
+        worker_path == "fused")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
@@ -139,17 +140,18 @@ def _int32_dots(hlo: str, scope: str) -> list[str]:
             and re.search(r"= s32\[[^=]*\b(dot|convolution)\(", line)]
 
 
-@pytest.mark.parametrize("cell, use_kernel", [
-    ("mnist37", None), ("mnist10-p30", None), ("mnist37", False)],
-    ids=["mnist37", "mnist10-p30", "mnist37-reference"])
+@pytest.mark.parametrize("cell, worker_path", [
+    ("mnist37", None), ("mnist10-p30", None), ("mnist37", "reference")],
+    ids=["mnist37", "mnist10-p30", "mnist37-reference"],
+    indirect=["worker_path"])
 def test_default_worker_step_compiles_for_v5e(one_chip, for_tpu, cell,
-                                              use_kernel):
+                                              worker_path):
     """The worker step as the one-chip cells build it (the scan and the
     cluster round share it): on a TPU the default is the fused kernel, and
     no int32 dot is left under the worker's scope; forcing the reference
     shows what the check would find."""
     n, mk, d, c, p = CELLS[cell]
-    cfg = CPMLConfig(**CASE1, c=c, p=p, use_kernel=use_kernel)
+    cfg = CPMLConfig(**CASE1, c=c, p=p)
 
     def step(cbar, xs, ws):
         with jax.named_scope(engine.SCOPE_WORKER):
@@ -159,7 +161,7 @@ def test_default_worker_step_compiles_for_v5e(one_chip, for_tpu, cell,
         _sds((cfg.r + 1,), jnp.int32, one_chip),
         _sds((n, mk, d), jnp.int32, one_chip),
         _sds((n, d, c, cfg.r), jnp.int32, one_chip)).compile().as_text()
-    fused = use_kernel is None
+    fused = worker_path is None
     assert ("tpu_custom_call" in hlo) == fused
     assert (not _int32_dots(hlo, engine.SCOPE_WORKER)) == fused
 

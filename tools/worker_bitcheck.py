@@ -4,17 +4,16 @@ cell's share shapes, on the chip.
     python tools/worker_bitcheck.py
 
 For each shape, uniform field elements (with the values where balanced
-digits carry written into the first rows) go through
-``compute.workers_fn`` forced to the fused kernel and to the reference; all
-N workers' results must be equal bit for bit.  The epsilon cell's shares
-are a chip's 10 at a time, four times over.  Prints each path's steady
-seconds and the compiled program's temporary bytes (a copy of the shares
-before the kernel shows there), then ``cpml_worker_programs``.  The last
-line is ``{"ok": true, ...}`` only when every check passed.
+digits carry written into the first rows) go through the fused kernel
+(``coded_grad.coded_grad_workers``) and the vmapped reference
+(``ref.coded_grad_mc_ref``); all N workers' results must be equal bit for
+bit.  The epsilon cell's shares are a chip's 10 at a time, four times
+over.  Prints each path's steady seconds and the compiled program's
+temporary bytes (a copy of the shares before the kernel shows there).  The
+last line is ``{"ok": true, ...}`` only when every check passed.
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 import os
 import statistics
@@ -29,8 +28,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core import field, protocol  # noqa: E402
-from repro.core.protocol import compute  # noqa: E402
-from repro.obs.metrics import REGISTRY  # noqa: E402
+from repro.kernels import coded_grad, ref  # noqa: E402
 
 CASE1 = dict(N=40, K=13, T=1, r=1)
 # (name, workers a call, calls, mk, d, c, p)
@@ -58,9 +56,10 @@ def timed(fn, *args):
 def check_shape(name, n, calls, mk, d, c, p, seed=0) -> dict:
     cfg = protocol.CPMLConfig(**CASE1, c=c, p=p)
     cbar = jnp.asarray(protocol.poly_coeffs(cfg), jnp.int32)
-    fns = {k: jax.jit(compute.workers_fn(
-        dataclasses.replace(cfg, use_kernel=k), cbar))
-        for k in (True, False)}
+    fns = {"fused": jax.jit(lambda x, w: coded_grad.coded_grad_workers(
+               x, w, cbar, p)),
+           "reference": jax.jit(jax.vmap(
+               lambda x, w: ref.coded_grad_mc_ref(x, w, cbar, p)))}
     e = jnp.asarray(edges(p))
     out = {"shape": [n * calls, mk, d, c], "p": p, "equal": True}
     for call in range(calls):
@@ -74,13 +73,14 @@ def check_shape(name, n, calls, mk, d, c, p, seed=0) -> dict:
         for k, fn in fns.items():
             if call == 0:
                 comp = fn.lower(x, w).compile()
-                out[f"temp_bytes_{'fused' if k else 'reference'}"] = int(
+                out[f"temp_bytes_{k}"] = int(
                     comp.memory_analysis().temp_size_in_bytes)
             _, res[k] = timed(fn, x, w)
             if call == 0:
-                out[f"s_{'fused' if k else 'reference'}"] = statistics.median(
+                out[f"s_{k}"] = statistics.median(
                     timed(fn, x, w)[0] for _ in range(5))
-        equal = bool((np.asarray(res[True]) == np.asarray(res[False])).all())
+        equal = bool((np.asarray(res["fused"])
+                      == np.asarray(res["reference"])).all())
         out["equal"] &= equal
         del x, w, res
     print(f"{name}: {json.dumps(out)}", flush=True)
@@ -93,9 +93,6 @@ def main() -> int:
     if jax.default_backend() != "tpu":
         raise SystemExit("worker_bitcheck: no TPU")
     results = {s[0]: check_shape(*s) for s in SHAPES}
-    counters = {k: v["value"] for k, v in REGISTRY.snapshot().items()
-                if k.startswith("cpml_worker_programs")}
-    print(json.dumps(counters), flush=True)
     ok = all(r["equal"] for r in results.values())
     print(json.dumps({"ok": ok, "device": jax.devices()[0].device_kind}))
     return 0 if ok else 1
